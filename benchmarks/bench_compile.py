@@ -8,9 +8,9 @@ timed-out benchmarks).
 """
 from __future__ import annotations
 
-import multiprocessing as mp
 import time
 
+from repro.core.edt.shard import process_context
 from repro.core.poly import (Tiling, tile_dependence,
                              tile_dependence_projection)
 from repro.core.programs import PROGRAMS
@@ -54,8 +54,9 @@ def _timed_projection(name, dep_idx, tiles) -> tuple[float, bool]:
 
     Exact Fourier-Motzkin can blow up doubly-exponentially — the paper's own
     experiments had two such timeouts; a hard kill is the honest metric."""
-    q: mp.Queue = mp.Queue()
-    p = mp.Process(target=_proj_worker, args=(q, name, dep_idx, tiles))
+    ctx = process_context()
+    q = ctx.Queue()
+    p = ctx.Process(target=_proj_worker, args=(q, name, dep_idx, tiles))
     p.start()
     p.join(TIMEOUT_S)
     if p.is_alive():

@@ -32,13 +32,13 @@ Rows land in the CI JSON artifact via ``benchmarks/run.py --json``
 from __future__ import annotations
 
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 
 from repro.core.edt import (DeviceExecutor, ExecutionConfig, TiledTaskGraph,
                             run_graph_threaded, run_model, simulate_indexed,
                             synthesize_indexed)
+from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 
@@ -159,7 +159,7 @@ def run(emit=print, smoke: bool = False):
     model_rows = _models(emit, SMOKE_CASES if smoke else CASES)
     dcases = SMOKE_DISPATCH_CASES if smoke else DISPATCH_CASES
     need_pool = any(s > 1 for _, _, _, s, _ in dcases)
-    pool = ProcessPoolExecutor(max_workers=2) if need_pool else None
+    pool = process_pool(2) if need_pool else None
     try:
         dispatch_rows = _dispatch(emit, dcases, pool=pool)
     finally:

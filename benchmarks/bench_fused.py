@@ -40,13 +40,13 @@ Rows land in the CI JSON artifact via ``benchmarks/run.py --json``
 from __future__ import annotations
 
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 
 from repro.core.edt import (DeviceExecutor, ExecutionConfig, FusedExecutor,
                             TiledTaskGraph, host_execute, pack_origins,
                             synthesize_indexed)
+from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 from repro.kernels.stencils import SPECS, default_state, handwritten_solve
@@ -83,7 +83,7 @@ def run(emit=print, smoke: bool = False):
          "vs_handwritten,verified")
     rows = []
     need_pool = any(s > 1 for *_, s, _, _ in cases)
-    pool = ProcessPoolExecutor(max_workers=2) if need_pool else None
+    pool = process_pool(2) if need_pool else None
     try:
         for name, tiles, params, shards, extras, flagship in cases:
             rows += _case(emit, name, tiles, params, shards, extras,

@@ -38,11 +38,11 @@ from __future__ import annotations
 
 import os
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 
 from repro.core.edt import ExecutionConfig, TiledTaskGraph
+from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 
@@ -213,7 +213,7 @@ def shard_scale(emit=print, smoke: bool = False, pool=None, reps: int = 2):
     rows = []
     own = pool is None
     if own:
-        pool = ProcessPoolExecutor(max_workers=os.cpu_count() or 1)
+        pool = process_pool(os.cpu_count() or 1)
         pool.submit(int, 0).result()
     try:
         for name, tiles, params in suite:
@@ -256,8 +256,7 @@ def run(emit=print, smoke: bool = False, scale: bool = None):
         scale = True
     emit(",".join(CSV_FIELDS))
     rows = []
-    pool = ProcessPoolExecutor(
-        max_workers=max(1, min(max(SHARD_COUNTS), os.cpu_count() or 1)))
+    pool = process_pool(max(1, min(max(SHARD_COUNTS), os.cpu_count() or 1)))
     pool.submit(int, 0).result()   # absorb spawn cost before timing
     try:
         for name, tiles, params in suite:

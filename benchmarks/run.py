@@ -155,6 +155,8 @@ def main(argv=None) -> int:
                     help="write machine-readable results to PATH")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sections = section_registry()
     if args.only:
         sections = {args.only: sections[args.only]}
@@ -185,6 +187,8 @@ def main(argv=None) -> int:
         report["sections"][name] = {"ok": ok, "seconds": round(dt, 3),
                                     "data": data}
         print(f"# bench:{name} took {dt:.1f}s", flush=True)
+    from repro.core.edt.shard import stop_process_servers
+    stop_process_servers()
     if args.json:
         with open(args.json, "w") as f:
             json.dump(report, f, indent=2, sort_keys=True)

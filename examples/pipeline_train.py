@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.parallel.pipeline import (build_schedule, make_pipeline_loss,
                                      pipelined_forward, sequential_reference)
 
@@ -35,6 +36,7 @@ def stage_fn(p, x):
 
 
 def main():
+    use_compile_cache()
     mesh = jax.make_mesh((N_STAGES,), ("stage",))
     sched = build_schedule(N_MICRO, N_STAGES, tile_m=TILE_M)
     print(f"polyhedral schedule: {sched.n_tiles} microbatch tiles x "

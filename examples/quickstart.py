@@ -13,12 +13,14 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import AdamWConfig, apply_updates, init_state
 from repro.runtime import DriverConfig, TrainDriver
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("llama3.2-1b").smoke_config().replace(
         d_model=128, d_ff=256, n_layers=2, vocab=512, remat=False)
     model = build_model(cfg)

@@ -16,12 +16,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 
 ARCHS = ["llama3.2-1b", "deepseek-v3-671b", "rwkv6-1.6b"]
 
 
 def main():
+    use_compile_cache()
     for arch in ARCHS:
         cfg = get_config(arch).smoke_config().replace(remat=False)
         model = build_model(cfg)

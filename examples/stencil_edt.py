@@ -34,12 +34,14 @@ from repro.core.edt.codegen import (emit_autodec, emit_fused,
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 from repro.kernels.stencils import SPECS, default_state, reference_solve
+from repro.launch.compile_cache import use_compile_cache
 
 T_STEPS, N = 12, 64
 TILE = (3, 8)
 
 
 def main():
+    use_compile_cache()
     prog = PROGRAMS["stencil1d"]()
     graph = TiledTaskGraph(prog, {"S": Tiling(TILE)})
     params = {"T": T_STEPS, "N": N}

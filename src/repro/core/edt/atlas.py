@@ -408,12 +408,14 @@ def crossover(smoke: bool = False, workers: int = CROSSOVER_WORKERS,
     Rows: ``{program, size, n_tasks, path, seconds, per_task_us,
     verified}`` with ``path`` in {host_sim, device_replay,
     distributed_inline_2}.  The device path is warm (second run: dispatch
-    cost, not jit); a missing/broken jax stack records a skip row instead
-    of failing the atlas.  ``points`` maps each non-host path to the first
+    cost, not jit); an engine that fails raises, failing the atlas.
+    ``points`` maps each non-host path to the first
     size label where it beat the host, or None within this ladder.
     """
     import numpy as np
 
+    from .device import DeviceExecutor
+    from .distributed import run_distributed
     from .wavefront import simulate_indexed, synthesize_indexed
 
     say = emit or (lambda *a, **k: None)
@@ -423,13 +425,11 @@ def crossover(smoke: bool = False, workers: int = CROSSOVER_WORKERS,
     rows: list[dict] = []
     say("program,size,n_tasks,path,seconds,per_task_us,verified")
 
-    def row(size_label, n, path, seconds, verified, skipped=None):
+    def row(size_label, n, path, seconds, verified):
         r = {"program": "jacobi2d", "size": size_label, "n_tasks": n,
              "path": path, "seconds": round(seconds, 4),
              "per_task_us": round(1e6 * seconds / max(1, n), 3),
              "verified": bool(verified)}
-        if skipped:
-            r["skipped"] = skipped
         rows.append(r)
         say(f"jacobi2d,{size_label},{n},{path},{r['seconds']},"
             f"{r['per_task_us']},{r['verified']}")
@@ -444,29 +444,20 @@ def crossover(smoke: bool = False, workers: int = CROSSOVER_WORKERS,
         host_order = np.asarray(sim.exec_order)
         row(label, ig.n, "host_sim", host_s, len(sim.exec_order) == ig.n)
 
-        try:
-            from .device import DeviceExecutor
-            dev = DeviceExecutor(ig, schedule=sched)
-            dev.run()                              # cold: jit + transfer
-            t0 = time.perf_counter()
-            run = dev.run()                        # warm: dispatch cost
-            dev_s = time.perf_counter() - t0
-            ok = np.array_equal(run.exec_order, host_order)
-            row(label, ig.n, "device_replay", dev_s, ok)
-        except Exception as e:  # noqa: BLE001 — record the skip, keep going
-            row(label, ig.n, "device_replay", 0.0, False, skipped=repr(e))
+        dev = DeviceExecutor(ig, schedule=sched)
+        dev.run()                                  # cold: jit + transfer
+        t0 = time.perf_counter()
+        run = dev.run()                            # warm: dispatch cost
+        dev_s = time.perf_counter() - t0
+        ok = np.array_equal(run.exec_order, host_order)
+        row(label, ig.n, "device_replay", dev_s, ok)
 
-        try:
-            from .distributed import run_distributed
-            t0 = time.perf_counter()
-            drun = run_distributed(ig, ranks=2, engine="numpy",
-                                   transport="inline")
-            dist_s = time.perf_counter() - t0
-            ok = np.array_equal(drun.level_of, sched.level_of)
-            row(label, ig.n, "distributed_inline_2", dist_s, ok)
-        except Exception as e:  # noqa: BLE001
-            row(label, ig.n, "distributed_inline_2", 0.0, False,
-                skipped=repr(e))
+        t0 = time.perf_counter()
+        drun = run_distributed(ig, ranks=2, engine="numpy",
+                               transport="inline")
+        dist_s = time.perf_counter() - t0
+        ok = np.array_equal(drun.level_of, sched.level_of)
+        row(label, ig.n, "distributed_inline_2", dist_s, ok)
 
     points: dict[str, Optional[str]] = {}
     host = {r["size"]: r["per_task_us"] for r in rows

@@ -168,10 +168,11 @@ class Session:
     def pool(self):
         """The session's executor pool, created lazily and owned if so."""
         if self._pool is None:
-            from concurrent.futures import ProcessPoolExecutor
+            # forkserver workers: a cold request after a device run must
+            # not fork the process that holds the accelerator
+            from .shard import process_pool
             n = self.config.resolve_shards() or (os.cpu_count() or 1)
-            self._pool = ProcessPoolExecutor(
-                max_workers=max(1, min(n, os.cpu_count() or 1)))
+            self._pool = process_pool(max(1, min(n, os.cpu_count() or 1)))
             self._own_pool = True
         return self._pool
 

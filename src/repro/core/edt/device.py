@@ -20,8 +20,8 @@ Two sweeps share the packed graph:
   over the transpose-CSR edge columns (gather + cumsum + boundary
   difference — XLA's scatter-add is ~10x slower on CPU for million-edge
   graphs), available either as fused jnp ops or as a pallas kernel
-  (``use_pallas=True``; ``interpret=True`` on CPU-only hosts, the same
-  fallback the ``repro.kernels`` layer uses).  Work is
+  (``use_pallas=True``), which runs in interpret mode only: Mosaic refuses
+  it for the TPU (:func:`make_pallas_step`).  Work is
   ``O(depth * (V + E))`` — the dense-frontier tradeoff every fixed-shape
   runtime makes.
 * **replay** (schedule packed too) — the million-task path.  Edges are
@@ -227,30 +227,33 @@ def make_pallas_step(n: int, n_edges: int, interpret: Optional[bool] = None):
 
     The kernel reads the counters, the frontier, and the transpose-CSR edge
     columns as whole-array blocks and writes the decremented counters plus
-    the newly-ready mask.  On CPU-only hosts it runs under
-    ``interpret=True`` (the container default, matching ``repro.kernels``);
-    on a real TPU the same body compiles, though a production kernel would
-    tile the edge columns through VMEM (see docs/device_exec.md).  Raises
-    ``RuntimeError`` when the installed jax has no pallas — callers fall
-    back to the XLA step, which is observably identical
-    (tests/test_device_exec.py asserts it against
+    the newly-ready mask.  It exists in interpret mode only: Mosaic, the
+    TPU kernel compiler, cannot lower its vector gather
+    (``tests/test_tpu_compile.py`` pins the refusal).  ``interpret``
+    defaults to True on the CPU backend and False elsewhere, and asking
+    for the compiled kernel off the CPU raises ``NotImplementedError``
+    here, at construction, instead of a compiler error inside the first
+    sweep.  The XLA step computes the same decrement
+    (tests/test_device_exec.py asserts both against
     :func:`decrement_reference`).
     """
-    # compat imports jax at module scope; defer so that importing this
-    # module (and therefore repro.core.edt, incl. in every ProcessPool
-    # worker of the sharded engine) stays jax-free on the host-only paths
-    from ... import compat
-
-    pl = compat.pallas()
-    if pl is None:
-        raise RuntimeError(
-            "this jax build has no pallas module; use the default XLA step "
-            "(DeviceExecutor(use_pallas=False)) — it is observably identical")
+    # deferred so that importing this module (and therefore
+    # repro.core.edt, incl. in every ProcessPool worker of the sharded
+    # engine) stays jax-free on the host-only paths
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
     if interpret is None:
         interpret = _interpret_default()
+    if not interpret and jax.default_backend() != "cpu":
+        raise NotImplementedError(
+            f"use_pallas=True has no compiled kernel on "
+            f"{jax.default_backend()}: Mosaic (the TPU kernel compiler) "
+            "refuses the pallas decrement kernel with 'Only 2D gather is "
+            "supported' on its vector gather frontier[dec_src].  Use the "
+            "XLA step (use_pallas=False), which computes the same "
+            "decrement, or interpret=True to run the kernel interpreted")
     if n_edges == 0:
         # zero-length blocks break the pallas interpreter, and an edgeless
         # graph has a trivial step: nothing decrements, nothing becomes ready
@@ -465,30 +468,37 @@ class DeviceExecutor:
             return self._run_replay()
         return self._run_discover()
 
-    def _run_discover(self) -> DeviceRun:
+    def _build_discover(self):
+        """The jitted discover sweep over the state tuple ``(indeg,
+        frontier, level, level_of, started, max_width)``."""
         import jax
+        import jax.numpy as jnp
+
+        dg = self.dg
+        step = (self._pallas_step if self.use_pallas else _step_xla(jnp))
+        dec_src = jnp.asarray(dg.dec_src)
+        dec_ptr = jnp.asarray(dg.dec_ptr)
+
+        def cond(state):
+            return state[1].any()
+
+        def body(state):
+            indeg, frontier, level, level_of, started, maxw = state
+            w = frontier.sum().astype(jnp.int32)
+            level_of = jnp.where(frontier, level, level_of)
+            indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
+            return (indeg, newly, level + 1, level_of, started + w,
+                    jnp.maximum(maxw, w))
+
+        return jax.jit(lambda s: jax.lax.while_loop(cond, body, s))
+
+    def _run_discover(self) -> DeviceRun:
         import jax.numpy as jnp
 
         dg = self.dg
         n = dg.n
         if self._discover_fn is None:
-            step = (self._pallas_step if self.use_pallas else _step_xla(jnp))
-            dec_src = jnp.asarray(dg.dec_src)
-            dec_ptr = jnp.asarray(dg.dec_ptr)
-
-            def cond(state):
-                return state[1].any()
-
-            def body(state):
-                indeg, frontier, level, level_of, started, maxw = state
-                w = frontier.sum().astype(jnp.int32)
-                level_of = jnp.where(frontier, level, level_of)
-                indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
-                return (indeg, newly, level + 1, level_of, started + w,
-                        jnp.maximum(maxw, w))
-
-            self._discover_fn = jax.jit(
-                lambda s: jax.lax.while_loop(cond, body, s))
+            self._discover_fn = self._build_discover()
         pred_host = dg.pred_n
         if self.faults is not None:
             # DROPPED_DECREMENT: the counter is initialized one too high,
@@ -532,55 +542,64 @@ class DeviceExecutor:
                                   widths)
         return DeviceRun("discover", levels, level_of, counters)
 
-    def _run_replay(self) -> DeviceRun:
+    def _build_replay(self):
+        """The jitted, validating replay sweep over the counters
+        ``indeg`` (``i32[n+1]``, slot ``n`` absorbs padded lanes)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
         dg, ds = self.dg, self.ds
         n, depth, w_pad, e_pad = dg.n, ds.depth, ds.w_pad, ds.e_pad
+        op = jnp.asarray(ds.order)
+        tp = jnp.asarray(ds.task_ptr)
+        ep = jnp.asarray(ds.edge_ptr)
+        tg = jnp.asarray(ds.lvl_tgt)
+
+        @jax.jit
+        def sweep(indeg):
+            aw = jnp.arange(w_pad, dtype=jnp.int32)
+            ae = jnp.arange(e_pad, dtype=jnp.int32)
+
+            def body(level, carry):
+                indeg, not_ready, early, maxw = carry
+                w = tp[level + 1] - tp[level]
+                ids = lax.dynamic_slice(op, (tp[level],), (w_pad,))
+                # (a) every task of this level must have a drained
+                # counter when it starts
+                not_ready += jnp.sum(
+                    jnp.where(aw < w, indeg[ids] != 0, False))
+                # (b) no task of the NEXT level may be ready before this
+                # level's decrements run — it would have been in an
+                # earlier frontier.  Checked level by level, this pins
+                # every task's drain to exactly the level before its own.
+                nw = tp[level + 2] - tp[level + 1]
+                nids = lax.dynamic_slice(op, (tp[level + 1],), (w_pad,))
+                early += jnp.sum(
+                    jnp.where(aw < nw, indeg[nids] == 0, False))
+                # decrement this wavefront's out-edges (contiguous slice)
+                ec = ep[level + 1] - ep[level]
+                tgts = lax.dynamic_slice(tg, (ep[level],), (e_pad,))
+                tgts = jnp.where(ae < ec, tgts, n)
+                indeg = indeg.at[tgts].add(-1)
+                return indeg, not_ready, early, jnp.maximum(maxw, w)
+
+            z = jnp.int32(0)
+            indeg, not_ready, early, maxw = lax.fori_loop(
+                0, depth, body, (indeg, z, z, z))
+            # (c) every counter fully consumed: each edge signaled once
+            undrained = jnp.sum(indeg[:n] != 0)
+            return not_ready, early, undrained, maxw
+
+        return sweep
+
+    def _run_replay(self) -> DeviceRun:
+        import jax.numpy as jnp
+
+        dg, ds = self.dg, self.ds
+        n, depth = dg.n, ds.depth
         if self._replay_fn is None:
-            op = jnp.asarray(ds.order)
-            tp = jnp.asarray(ds.task_ptr)
-            ep = jnp.asarray(ds.edge_ptr)
-            tg = jnp.asarray(ds.lvl_tgt)
-
-            @jax.jit
-            def sweep(indeg):
-                aw = jnp.arange(w_pad, dtype=jnp.int32)
-                ae = jnp.arange(e_pad, dtype=jnp.int32)
-
-                def body(level, carry):
-                    indeg, not_ready, early, maxw = carry
-                    w = tp[level + 1] - tp[level]
-                    ids = lax.dynamic_slice(op, (tp[level],), (w_pad,))
-                    # (a) every task of this level must have a drained
-                    # counter when it starts
-                    not_ready += jnp.sum(
-                        jnp.where(aw < w, indeg[ids] != 0, False))
-                    # (b) no task of the NEXT level may be ready before this
-                    # level's decrements run — it would have been in an
-                    # earlier frontier.  Checked level by level, this pins
-                    # every task's drain to exactly the level before its own.
-                    nw = tp[level + 2] - tp[level + 1]
-                    nids = lax.dynamic_slice(op, (tp[level + 1],), (w_pad,))
-                    early += jnp.sum(
-                        jnp.where(aw < nw, indeg[nids] == 0, False))
-                    # decrement this wavefront's out-edges (contiguous slice)
-                    ec = ep[level + 1] - ep[level]
-                    tgts = lax.dynamic_slice(tg, (ep[level],), (e_pad,))
-                    tgts = jnp.where(ae < ec, tgts, n)
-                    indeg = indeg.at[tgts].add(-1)
-                    return indeg, not_ready, early, jnp.maximum(maxw, w)
-
-                z = jnp.int32(0)
-                indeg, not_ready, early, maxw = lax.fori_loop(
-                    0, depth, body, (indeg, z, z, z))
-                # (c) every counter fully consumed: each edge signaled once
-                undrained = jnp.sum(indeg[:n] != 0)
-                return not_ready, early, undrained, maxw
-
-            self._replay_fn = sweep
+            self._replay_fn = self._build_replay()
         # slot n swallows sentinel/padded decrements and gathers
         indeg0 = jnp.concatenate([jnp.asarray(dg.pred_n),
                                   jnp.zeros(1, jnp.int32)])

@@ -49,7 +49,8 @@ Two rank engines share the partition:
 
 Transports: ``inline`` round-robins every rank in one process (deterministic,
 test- and device-friendly); ``processes`` spawns one OS process per rank
-with multiprocessing queues as the message fabric (``start_method="spawn"``
+with multiprocessing queues as the message fabric (forkserver by default,
+:func:`~repro.core.edt.shard.process_context`; ``start_method="spawn"``
 safe; ``jax.distributed`` multi-controller would slot in at this seam —
 the engines only ever see :class:`MsgBatch` objects).
 
@@ -610,7 +611,9 @@ def _run_processes(slices, faults: Optional[FaultPlan], attempt: int,
     """One OS process per rank, multiprocessing queues as the fabric."""
     import multiprocessing as mp
 
-    ctx = mp.get_context(start_method) if start_method else mp.get_context()
+    from .shard import process_context
+
+    ctx = mp.get_context(start_method) if start_method else process_context()
     inboxes = [ctx.Queue() for _ in slices]
     result_q = ctx.Queue()
     procs = [ctx.Process(target=_rank_worker,

@@ -52,9 +52,11 @@ Both sweep modes run fused: **replay** is the ``O(V+E)`` leveled
 ``fori_loop`` with the on-device schedule validation counters; **discover**
 self-levels from the counters alone (``while_loop``, dense frontier — the
 documented ``O(depth·V·g)`` test-scale tradeoff) with optional pallas
-decrement.  Packed products (``DeviceGraph``, ``DeviceSchedule``, origin
-columns) flow through :meth:`GraphCache.fused` / :meth:`Session.fused_packed`
-so warm runs skip every host-side pack.  See ``docs/device_exec.md``
+decrement (interpret mode only, see
+:func:`~repro.core.edt.device.make_pallas_step`).  Packed products
+(``DeviceGraph``, ``DeviceSchedule``, origin columns) flow through
+:meth:`GraphCache.fused` / :meth:`Session.fused_packed` so warm runs skip
+every host-side pack.  See ``docs/device_exec.md``
 ("Fused execution") for the measured numbers.
 """
 from __future__ import annotations
@@ -235,8 +237,8 @@ class FusedExecutor:
 
     ``state`` seeds the grid (default
     :func:`~repro.kernels.stencils.default_state`); ``dtype`` defaults to
-    the state's (float64 requires x64 jax — use
-    :func:`repro.compat.enable_x64`).  ``run()`` returns a
+    the state's (float64 requires x64 jax — run inside
+    ``jax.enable_x64(True)``).  ``run()`` returns a
     :class:`FusedRun`; repeat runs (optionally with a fresh ``state=``)
     reuse the compiled sweep and pay dispatch cost only.
     """
@@ -344,7 +346,7 @@ class FusedExecutor:
         if self.dtype == np.float64 and not jax.config.jax_enable_x64:
             raise RuntimeError(
                 "float64 fused execution needs 64-bit jax types; wrap the "
-                "run in repro.compat.enable_x64()")
+                "run in jax.enable_x64(True)")
 
     def _flat_state(self, a0: "np.ndarray") -> "np.ndarray":
         size = self.size
@@ -498,33 +500,40 @@ class FusedExecutor:
         counters = DeviceCounters(dg.n, dg.n, maxw, ds.depth, widths)
         return self._finish("replay", ds.levels, ds.level_of, counters, u)
 
-    def _run_discover(self, a0: "np.ndarray") -> FusedRun:
+    def _build_discover(self):
+        """The jitted discover sweep over the state tuple ``(indeg,
+        frontier, level, level_of, started, max_width, u)``."""
         import jax
+        import jax.numpy as jnp
+
+        dg = self.dg
+        step = (self._pallas_step if self.use_pallas else _step_xla(jnp))
+        dec_src = jnp.asarray(dg.dec_src)
+        dec_ptr = jnp.asarray(dg.dec_ptr)
+        org = jnp.asarray(self.fo[:dg.n])
+        compute = self._make_compute(jnp)
+
+        def cond(state):
+            return state[1].any()
+
+        def body(state):
+            indeg, frontier, level, level_of, started, maxw, u = state
+            w = frontier.sum().astype(jnp.int32)
+            level_of = jnp.where(frontier, level, level_of)
+            u = compute(u, org, active=frontier)
+            indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
+            return (indeg, newly, level + 1, level_of, started + w,
+                    jnp.maximum(maxw, w), u)
+
+        return jax.jit(lambda s: jax.lax.while_loop(cond, body, s))
+
+    def _run_discover(self, a0: "np.ndarray") -> FusedRun:
         import jax.numpy as jnp
 
         dg = self.dg
         n = dg.n
         if self._discover_fn is None:
-            step = (self._pallas_step if self.use_pallas else _step_xla(jnp))
-            dec_src = jnp.asarray(dg.dec_src)
-            dec_ptr = jnp.asarray(dg.dec_ptr)
-            org = jnp.asarray(self.fo[:n])
-            compute = self._make_compute(jnp)
-
-            def cond(state):
-                return state[1].any()
-
-            def body(state):
-                indeg, frontier, level, level_of, started, maxw, u = state
-                w = frontier.sum().astype(jnp.int32)
-                level_of = jnp.where(frontier, level, level_of)
-                u = compute(u, org, active=frontier)
-                indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
-                return (indeg, newly, level + 1, level_of, started + w,
-                        jnp.maximum(maxw, w), u)
-
-            self._discover_fn = jax.jit(
-                lambda s: jax.lax.while_loop(cond, body, s))
+            self._discover_fn = self._build_discover()
         pred_host = dg.pred_n
         if self.faults is not None:
             dropped = [int(t) for t in self.faults.dropped_tasks()]

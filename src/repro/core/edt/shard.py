@@ -52,6 +52,7 @@ Entry points:
 """
 from __future__ import annotations
 
+import multiprocessing
 import os
 import weakref
 from concurrent.futures import Executor, ProcessPoolExecutor
@@ -66,6 +67,43 @@ from .recovery import RetryPolicy, run_round
 
 TILES = "tiles"
 EDGES = "edges"
+
+
+def process_context():
+    """The multiprocessing context of every pool and rank process here.
+
+    ``forkserver``: children fork from a server that starts as a fresh
+    interpreter, so no child inherits the parent's threads or its JAX
+    client.  A process that has touched JAX holds the accelerator, and a
+    forked child would share that client.  The server preloads
+    :mod:`repro.core.edt` (jax-free), so a worker starts without
+    re-importing NumPy and the polyhedral core.
+    """
+    ctx = multiprocessing.get_context("forkserver")
+    ctx.set_forkserver_preload(["repro.core.edt"])
+    return ctx
+
+
+def stop_process_servers() -> None:
+    """Stop the forkserver and the resource tracker, waiting for both.
+
+    :func:`process_context` starts both on first use and they outlive every
+    pool, exiting only some time after this process does.  An entry point
+    calls this once its pools are shut down, so that it leaves no process
+    running when it returns.  A later pool starts them again.
+    """
+    from multiprocessing import forkserver, resource_tracker
+
+    forkserver._forkserver._stop()
+    resource_tracker._resource_tracker._stop()
+
+
+def process_pool(max_workers: int) -> ProcessPoolExecutor:
+    """A process pool whose workers never inherit the parent's JAX
+    client (:func:`process_context`)."""
+    return ProcessPoolExecutor(max_workers=max_workers,
+                               mp_context=process_context())
+
 
 # Blocks per unit beyond the shard count: outer-dim blocks of equal extent
 # carry unequal point counts (triangular domains), so oversubscription keeps
@@ -563,8 +601,8 @@ def scan_sharded(graph, params: dict, shards: int,
     returning pickled blocks and concatenating.
 
     ``pool`` lets callers amortize one ``ProcessPoolExecutor`` over many
-    calls (benchmarks, services); by default a pool of ``min(shards,
-    cpu_count)`` workers is spawned and torn down per call.
+    calls (benchmarks, services); by default a :func:`process_pool` of
+    ``min(shards, cpu_count)`` workers is started and torn down per call.
 
     ``recovery`` (a :class:`~repro.core.edt.recovery.RetryPolicy`) arms
     per-round timeouts, dead-worker detection, and bounded backoff retry:
@@ -582,8 +620,7 @@ def scan_sharded(graph, params: dict, shards: int,
     segs = _Segments(enabled=use_shm)
     own = pool is None and bool(plan.tile_specs or plan.edge_specs)
     n_workers = max(1, min(shards, os.cpu_count() or 1))
-    factory = ((lambda: ProcessPoolExecutor(max_workers=n_workers))
-               if own else None)
+    factory = (lambda: process_pool(n_workers)) if own else None
     if own:
         pool = factory()
     rr = dict(policy=recovery, plan=faults, pool_factory=factory)

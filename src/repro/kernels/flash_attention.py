@@ -19,12 +19,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..compat import pallas, pallas_tpu, tpu_compiler_params
-
-# resolved at import so a pallas-less jax fails here, not mid-call; the
-# version shim (and its test monkeypatch point) lives in compat
-pl = pallas(required=True)
-pltpu = pallas_tpu(required=True)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -103,7 +99,7 @@ def flash_attention_hm(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq,), jnp.float32),       # l
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

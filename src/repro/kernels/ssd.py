@@ -16,12 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..compat import pallas, pallas_tpu, tpu_compiler_params
-
-# resolved at import so a pallas-less jax fails here, not mid-call; the
-# version shim (and its test monkeypatch point) lives in compat
-pl = pallas(required=True)
-pltpu = pallas_tpu(required=True)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, dt_ref, A_ref, b_ref, c_ref, s0_ref,
@@ -104,7 +100,7 @@ def ssd_pallas(x, dt, A, Bm, Cm, init_state=None, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, Bm, Cm, init_state)

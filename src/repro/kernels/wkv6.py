@@ -21,12 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..compat import pallas, pallas_tpu, tpu_compiler_params
-
-# resolved at import so a pallas-less jax fails here, not mid-call; the
-# version shim (and its test monkeypatch point) lives in compat
-pl = pallas(required=True)
-pltpu = pallas_tpu(required=True)
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
@@ -90,7 +86,7 @@ def wkv6_pallas(r, k, v, w, u, init_state=None, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, init_state)

@@ -34,7 +34,9 @@ import time
 from ..core import programs
 from ..core.edt.config import CachePolicy, ExecutionConfig, Session
 from ..core.edt.service import ScheduleService
+from ..core.edt.shard import stop_process_servers
 from ..core.poly import Tiling
+from .compile_cache import use_compile_cache
 
 
 def build_session(args) -> tuple[Session, object]:
@@ -147,15 +149,19 @@ def main(argv=None) -> int:
                     help="concurrent clients per key in --demo")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     session, graph = build_session(args)
-    with session:
-        service = ScheduleService(session)
-        try:
-            if args.demo:
-                return asyncio.run(demo(service, graph, args))
-            return asyncio.run(serve_stdin(service, graph))
-        finally:
-            service.close()
+    try:
+        with session:
+            service = ScheduleService(session)
+            try:
+                if args.demo:
+                    return asyncio.run(demo(service, graph, args))
+                return asyncio.run(serve_stdin(service, graph))
+            finally:
+                service.close()
+    finally:
+        stop_process_servers()
 
 
 if __name__ == "__main__":

@@ -19,7 +19,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from .config import ArchConfig
 from .layers import (gqa_apply, gqa_params, mla_apply, mla_params, mlp_apply,
                      mlp_params, moe_einsum_apply, moe_ep_apply, moe_params,
@@ -168,9 +167,9 @@ def _moe_dispatch(cfg: ArchConfig, pmoe, h, ctx: ParallelCtx):
         return moe_ep_apply(pp, xx, cfg_routed, ep_axis=ep_axis,
                             ep_size=ep_size)
 
-    out = compat.shard_map(region, mesh=ctx.mesh,
-                           in_specs=(tok_spec, pspecs),
-                           out_specs=tok_spec)(h, routed)
+    out = jax.shard_map(region, mesh=ctx.mesh,
+                        in_specs=(tok_spec, pspecs),
+                        out_specs=tok_spec, check_vma=False)(h, routed)
     if cfg.moe.n_shared:
         out = out + mlp_apply(pmoe["shared"], h, "swiglu")
     return out

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import compat
 from ..core.edt import TiledTaskGraph, synthesize
 from ..core.poly import Tiling
 from ..core.programs import pipeline as pipeline_program
@@ -111,10 +110,10 @@ def pipelined_forward(stage_fn: Callable, stage_params: PyTree,
         return jax.lax.psum(outs, axis)
 
     nd = microbatches.ndim
-    return compat.shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P(*([None] * nd))),
-        out_specs=P(*([None] * nd)),
+        out_specs=P(*([None] * nd)), check_vma=False,
     )(stage_params, microbatches)
 
 

@@ -4,10 +4,10 @@ The container does not ship hypothesis and nothing may be pip-installed.
 Test modules import via::
 
     try:
-        from hypothesis import HealthCheck, given, settings
+        from hypothesis import HealthCheck, example, given, settings
         from hypothesis import strategies as st
     except ImportError:
-        from hypo_stub import HealthCheck, given, settings, st
+        from hypo_stub import HealthCheck, example, given, settings, st
 
 When the real library is absent, ``@given`` replaces the test with a
 zero-argument function that calls ``pytest.skip`` — the deterministic tests
@@ -26,6 +26,12 @@ class HealthCheck:
 
 
 def settings(*_a, **_k):
+    def deco(fn):
+        return fn
+    return deco
+
+
+def example(*_a, **_k):
     def deco(fn):
         return fn
     return deco

@@ -18,7 +18,6 @@ coverage running either way.
 from __future__ import annotations
 
 import random
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 import pytest
@@ -30,7 +29,7 @@ except ImportError:  # property tests skip; deterministic tests still run
     from hypo_stub import HealthCheck, given, settings, st
 
 from repro.core.edt import ExecutionConfig, PolyhedralProgram, TiledTaskGraph
-from repro.core.edt.shard import plan_shards, scan_sharded
+from repro.core.edt.shard import plan_shards, process_pool, scan_sharded
 from repro.core.poly import Polyhedron, Tiling
 from repro.core.programs import PROGRAMS, dep
 
@@ -39,7 +38,7 @@ BACKENDS = ("fraction", "compiled", "numpy")
 
 @pytest.fixture(scope="module")
 def pool():
-    p = ProcessPoolExecutor(max_workers=2)
+    p = process_pool(2)
     p.submit(int, 0).result()   # absorb spawn cost
     yield p
     p.shutdown()

@@ -19,21 +19,18 @@ and its graceful absence, and the ≥1M-task jacobi2d acceptance run.
 """
 from __future__ import annotations
 
-import importlib
 import random
-import sys
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 import pytest
 from test_backend_differential import _build_program
 
-from repro import compat
 from repro.core.edt import (DeviceExecutor, ExecutionConfig, IndexedGraph,
                             TiledTaskGraph, levels_from_array,
                             simulate_indexed, synthesize_indexed)
 from repro.core.edt.device import (decrement_reference, make_pallas_step,
                                    pack_graph, pack_schedule)
+from repro.core.edt.shard import process_pool
 from repro.core.edt.wavefront import IndexedSchedule
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
@@ -43,7 +40,7 @@ BACKENDS = ("fraction", "compiled", "numpy")
 
 @pytest.fixture(scope="module")
 def pool():
-    p = ProcessPoolExecutor(max_workers=2)
+    p = process_pool(2)
     p.submit(int, 0).result()
     yield p
     p.shutdown()
@@ -255,31 +252,6 @@ def test_pallas_discover_run_identical():
     # replay scatter path under a "pallas" label would mislead
     with pytest.raises(TypeError, match="discover sweep only"):
         DeviceExecutor(ig, schedule=sched, use_pallas=True)
-
-
-def test_degrades_gracefully_without_pallas(monkeypatch):
-    """When jax has no pallas, importing device.py and the default XLA
-    path keep working; only ``use_pallas=True`` refuses, loudly."""
-    import jax.experimental
-
-    import repro.core.edt.device as device
-
-    monkeypatch.delattr(jax.experimental, "pallas", raising=False)
-    monkeypatch.setitem(sys.modules, "jax.experimental.pallas", None)
-    assert compat.pallas() is None
-    assert compat.has_pallas() is False
-    importlib.reload(device)            # module import never touches pallas
-    try:
-        ig, sched = _small_graph()
-        run = device.DeviceExecutor(ig).run()
-        assert [lv.tolist() for lv in run.levels] == [
-            lv.tolist() for lv in sched.levels]
-        with pytest.raises(RuntimeError, match="no pallas"):
-            device.DeviceExecutor(ig, use_pallas=True)
-    finally:
-        monkeypatch.undo()
-        importlib.reload(device)        # restore a clean module for others
-    assert compat.has_pallas() is True
 
 
 # ------------------------------------------------------------- at scale

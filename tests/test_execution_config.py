@@ -10,7 +10,10 @@ functional for out-of-tree callers.
 """
 from __future__ import annotations
 
-from concurrent.futures import ProcessPoolExecutor
+import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -19,6 +22,7 @@ from repro.core.edt import (DeviceExecutor, ExecutionConfig, Session,
                             TiledTaskGraph, synthesize, synthesize_indexed)
 from repro.core.edt.config import (DEFAULT_CONFIG, UNSET, CachePolicy,
                                    resolve_execution)
+from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 
@@ -27,7 +31,7 @@ PARAMS = {"N": 20}
 
 @pytest.fixture(scope="module")
 def pool():
-    p = ProcessPoolExecutor(max_workers=2)
+    p = process_pool(2)
     p.submit(int, 0).result()
     yield p
     p.shutdown()
@@ -155,3 +159,44 @@ def test_session_graph_uses_configured_backend():
     with Session(ExecutionConfig(backend="fraction")) as s:
         g = s.graph(PROGRAMS["trisolv"](), {"S": Tiling((4, 4))})
         assert g.backend == "fraction"
+
+
+_STOP_SCRIPT = textwrap.dedent("""
+    import os
+
+    from repro.core.edt import ExecutionConfig, Session
+    from repro.core.edt.shard import stop_process_servers
+    from repro.core.poly import Tiling
+    from repro.core.programs import PROGRAMS
+
+    if __name__ == "__main__":
+        with Session(ExecutionConfig(backend="numpy", shards=2)) as s:
+            g = s.graph(PROGRAMS["trisolv"](), {"S": Tiling((4, 4))})
+            s.index_graph(g, {"N": 20})
+            assert s.runtime_config().pool is not None
+        pid = os.getpid()
+        with open(f"/proc/{pid}/task/{pid}/children") as f:
+            print("before", len(f.read().split()))
+        stop_process_servers()
+        with open(f"/proc/{pid}/task/{pid}/children") as f:
+            print("after", len(f.read().split()))
+""")
+
+
+@pytest.mark.skipif(not os.path.exists("/proc/self/task"),
+                    reason="reads child processes from /proc")
+def test_stop_process_servers_leaves_no_child(tmp_path):
+    """A closed session's pool leaves the forkserver and the resource
+    tracker running; :func:`stop_process_servers` stops both, so an entry
+    point returns with no process of its own still alive."""
+    script = tmp_path / "entry.py"
+    script.write_text(_STOP_SCRIPT)
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       os.pardir, "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, str(script)], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    counts = dict(line.split() for line in out.stdout.splitlines())
+    assert int(counts["before"]) == 2      # forkserver + resource tracker
+    assert int(counts["after"]) == 0

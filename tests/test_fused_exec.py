@@ -20,17 +20,16 @@ graph-cache ``fused`` product, and the ≥1M-task jacobi2d acceptance run.
 """
 from __future__ import annotations
 
-from concurrent.futures import ProcessPoolExecutor
-
+import jax
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core.edt import (CachePolicy, ExecutionConfig, FusedExecutor,
                             GraphCache, Session, TiledTaskGraph,
                             graph_tile, host_execute, pack_origins,
                             simulate_indexed, synthesize_indexed)
 from repro.core.edt.fused import SENTINEL_ORIGIN
+from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
 from repro.kernels.stencils import (SPECS, default_state, handwritten_solve,
@@ -52,7 +51,7 @@ F64_TOL = dict(rtol=1e-12, atol=1e-13)  # observed ~1e-16
 
 @pytest.fixture(scope="module")
 def pool():
-    p = ProcessPoolExecutor(max_workers=2)
+    p = process_pool(2)
     p.submit(int, 0).result()
     yield p
     p.shutdown()
@@ -107,7 +106,7 @@ def test_fused_matches_reference_f64(name, tiles, params):
     ig, sched = synthesize_indexed(g, params)
     state = default_state(spec, params["N"], np.float64)
     want = reference_solve(spec, state, params["T"])
-    with compat.enable_x64():
+    with jax.enable_x64(True):
         for sched_arg in (sched, None):
             run = FusedExecutor(ig, params, body=name, tile=tiles,
                                 schedule=sched_arg, state=state).run()
@@ -280,8 +279,6 @@ def test_f64_without_x64_raises():
 def test_fused_discover_pallas_interpret():
     """The pallas decrement composes with the fused compute (interpret
     mode on this CPU container)."""
-    if not compat.has_pallas():            # pragma: no cover - env guard
-        pytest.skip("jax build has no pallas")
     name, tiles, params = CASES[0]
     spec = SPECS[name]
     g = _graph(name, tiles)

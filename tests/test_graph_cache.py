@@ -17,13 +17,13 @@ from __future__ import annotations
 import asyncio
 import threading
 import time
-from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
 import pytest
 
 from repro.core.edt import (CachePolicy, ExecutionConfig, GraphCache,
                             ScheduleService, Session, graph_cache_info)
+from repro.core.edt.shard import process_pool
 from repro.core.edt.taskgraph import TiledTaskGraph
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
@@ -33,7 +33,7 @@ BACKENDS = ("fraction", "compiled", "numpy")
 
 @pytest.fixture(scope="module")
 def pool():
-    p = ProcessPoolExecutor(max_workers=2)
+    p = process_pool(2)
     p.submit(int, 0).result()
     yield p
     p.shutdown()
@@ -190,6 +190,12 @@ def test_concurrent_service_requests_materialize_once():
 
     def counting(params, cfg, scans=None):
         calls.append(dict(params))
+        # hold the fill until the other clients have found it in flight:
+        # on a loaded host it could otherwise finish before they probe,
+        # and they would count as warm hits instead of coalescing
+        deadline = time.monotonic() + 30
+        while service.coalesced < 7 and time.monotonic() < deadline:
+            time.sleep(0.001)
         return inner(params, cfg, scans=scans)
 
     g._index_graph_cfg = counting

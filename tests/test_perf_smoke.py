@@ -10,6 +10,8 @@ slower than compiled, on a real materialize.
 import json
 import time
 
+import pytest
+
 from repro.core.edt import TiledTaskGraph
 from repro.core.poly import Tiling
 from repro.core.programs import PROGRAMS
@@ -81,10 +83,12 @@ def test_bench_sync_and_executor_smoke():
         assert r["per_task_us"] > 0
 
 
-def test_run_harness_smoke_mode(tmp_path):
+def test_run_harness_smoke_mode(tmp_path, monkeypatch):
     """`python -m benchmarks.run --smoke --only taskgen --json F` exits
     cleanly and writes the stable artifact schema."""
     from benchmarks import run as harness
+    # the harness places JAX's compile cache; keep it off this process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     path = tmp_path / "perf.json"
     assert harness.main(["--smoke", "--only", "taskgen",
                          "--json", str(path)]) == 0
@@ -237,3 +241,30 @@ def test_numpy_enum_not_slower_than_compiled():
     t_n = time.perf_counter() - t0
     assert ig.n == len(mc.tasks) and ig.n_edges == mc.n_edges
     assert t_n < t_c
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_placement(env_set, tmp_path, monkeypatch):
+    """Entry points keep JAX's compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads it; the code sets
+    nothing), else at the fixed ``<checkout>/.jax_cache``."""
+    import pathlib
+
+    import jax
+
+    from repro.launch.compile_cache import CHECKOUT_CACHE, use_compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == old
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            checkout = pathlib.Path(__file__).resolve().parents[1]
+            assert CHECKOUT_CACHE == checkout / ".jax_cache"
+            assert use_compile_cache() == str(CHECKOUT_CACHE)
+            assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

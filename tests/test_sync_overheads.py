@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import json
 
+import pytest
+
 from repro.core.edt import (MODELS, PolyhedralProgram, TiledTaskGraph, atlas,
                             validate_order)
 from repro.core.poly import Tiling
@@ -245,7 +247,19 @@ def test_atlas_crossover_smoke_verified():
     paths = {r["path"] for r in res["rows"]}
     assert paths == {"host_sim", "device_replay", "distributed_inline_2"}
     for r in res["rows"]:
-        if r["path"] == "host_sim" or "skipped" not in r:
-            assert r["verified"], r
-            assert r["per_task_us"] > 0
+        assert r["verified"], r
+        assert r["per_task_us"] > 0
     assert set(res["points"]) == {"device_replay", "distributed_inline_2"}
+
+
+def test_atlas_crossover_device_failure_fails_the_atlas(monkeypatch):
+    """A device engine that fails raises out of the crossover: no row
+    records it as skipped while the section reports success."""
+    from repro.core.edt import device
+
+    def broken(self):
+        raise RuntimeError("device sweep failed")
+
+    monkeypatch.setattr(device.DeviceExecutor, "run", broken)
+    with pytest.raises(RuntimeError, match="device sweep failed"):
+        atlas.crossover(smoke=True)

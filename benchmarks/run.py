@@ -12,7 +12,6 @@ Sections:
               ≥1M-task shard-scale curve
   sync      — §2 Table 2: overhead counters per synchronization model
   executor  — §5.2: makespan comparison across models (+ threaded autodec)
-  roofline  — §Roofline terms from the dry-run artifacts (if present)
   faults    — recovery overhead: fault-free vs one recoverable injected
               worker crash at 2/4 shards, recovered arrays verified
               byte-identical (docs/robustness.md)
@@ -127,15 +126,14 @@ def encode_section_data(data):
 def section_registry() -> dict:
     """Name -> run function for every benchmark section (import on call)."""
     from . import (bench_compile, bench_distributed, bench_executor,
-                   bench_faults, bench_fused, bench_roofline,
-                   bench_service, bench_sync_overheads, bench_taskgen)
+                   bench_faults, bench_fused, bench_service,
+                   bench_sync_overheads, bench_taskgen)
 
     return {
         "compile": bench_compile.run,
         "taskgen": bench_taskgen.run,
         "sync": bench_sync_overheads.run,
         "executor": bench_executor.run,
-        "roofline": bench_roofline.run,
         "faults": bench_faults.run,
         "service": bench_service.run,
         "fused": bench_fused.run,
@@ -147,8 +145,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     choices=[None, "compile", "taskgen", "sync", "executor",
-                             "roofline", "faults", "service", "fused",
-                             "distributed"])
+                             "faults", "service", "fused", "distributed"])
     ap.add_argument("--smoke", action="store_true",
                     help="fast subset of each section (sub-minute total)")
     ap.add_argument("--json", default=None, metavar="PATH",

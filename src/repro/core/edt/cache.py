@@ -51,6 +51,7 @@ from typing import Optional
 
 import numpy as np
 
+from . import tracing
 from .config import CachePolicy, ExecutionConfig
 
 #: Live caches, for module-level introspection (weakly held).
@@ -235,19 +236,21 @@ class GraphCache:
         if not self.policy.enabled:
             with self._lock:
                 self.misses += 1
-            return graph._index_graph_cfg(params, cfg)
+            with tracing.span("scan"):
+                return graph._index_graph_cfg(params, cfg)
         key = self._key(graph, params)
         ig = self._lookup(key, "ig")
         if ig is not None:
             return ig
-        donor = None
-        if self.policy.incremental:
-            with self._lock:
-                donor = self._find_donor_locked(key, graph)
-        if donor is not None:
-            ig = self._incremental(graph, donor, params, cfg)
-        if ig is None:
-            ig = graph._index_graph_cfg(params, cfg)
+        with tracing.span("scan"):
+            donor = None
+            if self.policy.incremental:
+                with self._lock:
+                    donor = self._find_donor_locked(key, graph)
+            if donor is not None:
+                ig = self._incremental(graph, donor, params, cfg)
+            if ig is None:
+                ig = graph._index_graph_cfg(params, cfg)
         return self._store(key, params, "ig", ig, ig.nbytes)
 
     def schedule(self, graph, params: dict,
@@ -256,11 +259,13 @@ class GraphCache:
         from .wavefront import schedule_from_graph
         ig = self.graph(graph, params, cfg)
         if not self.policy.enabled:
-            return ig, schedule_from_graph(ig)
+            with tracing.span("level"):
+                return ig, schedule_from_graph(ig)
         key = self._key(graph, params)
         sched = self._lookup(key, "schedule")
         if sched is None:
-            s = schedule_from_graph(ig)
+            with tracing.span("level"):
+                s = schedule_from_graph(ig)
             sched = self._store(key, params, "schedule", s, _sched_nbytes(s))
         return ig, sched
 
@@ -270,11 +275,13 @@ class GraphCache:
         from .device import pack_graph
         ig = self.graph(graph, params, cfg)
         if not self.policy.enabled:
-            return pack_graph(ig)
+            with tracing.span("pack"):
+                return pack_graph(ig)
         key = self._key(graph, params)
         dg = self._lookup(key, "dg")
         if dg is None:
-            dg = pack_graph(ig)
+            with tracing.span("pack"):
+                dg = pack_graph(ig)
             dg = self._store(key, params, "dg", dg, _dg_nbytes(dg))
         return dg
 
@@ -289,11 +296,13 @@ class GraphCache:
         ig, sched = self.schedule(graph, params, cfg)
         dg = self.packed_graph(graph, params, cfg)
         if not self.policy.enabled:
-            return dg, pack_schedule(ig, sched)
+            with tracing.span("pack"):
+                return dg, pack_schedule(ig, sched)
         key = self._key(graph, params)
         ds = self._lookup(key, "ds")
         if ds is None:
-            ds = pack_schedule(ig, sched)
+            with tracing.span("pack"):
+                ds = pack_schedule(ig, sched)
             ds = self._store(key, params, "ds", ds, _ds_nbytes(ds))
         return dg, ds
 
@@ -312,12 +321,14 @@ class GraphCache:
         dg, ds = self.packed(graph, params, cfg)
         if not self.policy.enabled:
             ig = self.graph(graph, params, cfg)
-            return dg, ds, pack_origins(ig, graph_tile(graph))
+            with tracing.span("pack"):
+                return dg, ds, pack_origins(ig, graph_tile(graph))
         key = self._key(graph, params)
         fo = self._lookup(key, "fo")
         if fo is None:
             ig = self.graph(graph, params, cfg)
-            fo = pack_origins(ig, graph_tile(graph))
+            with tracing.span("pack"):
+                fo = pack_origins(ig, graph_tile(graph))
             fo = self._store(key, params, "fo", fo, int(fo.nbytes))
         return dg, ds, fo
 

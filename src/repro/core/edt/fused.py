@@ -68,6 +68,7 @@ from typing import Optional, Union
 import numpy as np
 
 from ...kernels.stencils import SPECS, StencilSpec, default_state
+from . import tracing
 from .config import resolve_execution
 from .device import (DeviceCounters, _counter_summary, _diagnose_replay,
                      _step_xla, make_pallas_step, pack_graph, pack_schedule)
@@ -360,14 +361,18 @@ class FusedExecutor:
         ``org`` is the ``(w, ndim)`` int32 origin rows (sentinel rows mask
         themselves through ``t < 0``); ``active`` optionally masks lanes
         (the discover frontier).  Sub-steps unroll statically; each is
-        3^d masked gathers, a weighted sum, and one dropped-OOB scatter.
+        3^d masked gathers, a weighted sum, and one dropped-OOB scatter,
+        all under the device scope ``edt.body``.
         """
+        import jax
+
         spec, steps, extent = self.spec, self.steps, self.extent
         size = self.size
         st = _strides(spec.space, extent)
         loc_steps = self._loc_steps
         taps = spec.taps
 
+        @jax.named_scope("edt.body")
         def compute(u, org, active=None):
             t0 = org[:, 0]
             osp = org[:, 1:]
@@ -427,11 +432,52 @@ class FusedExecutor:
             return self._run_replay(a0)
         return self._run_discover(a0)
 
+    def _sweep(self):
+        """The jitted sweep of this executor's mode, built on first use."""
+        if self.ds is not None:
+            if self._replay_fn is None:
+                with tracing.span("build"):
+                    self._replay_fn = self._build_replay()
+            return self._replay_fn
+        if self._discover_fn is None:
+            with tracing.span("build"):
+                self._discover_fn = self._build_discover()
+        return self._discover_fn
+
+    def compiled(self):
+        """The sweep :meth:`run` calls, compiled ahead of time for this
+        executor's own argument shapes (a load from JAX's persistent
+        compile cache where it holds the sweep).  Its
+        ``memory_analysis()`` gives the generated code's bytes, and its
+        ``as_text()`` the HLO, each op's metadata naming its ``edt.*``
+        device scope."""
+        import jax
+
+        n = self.dg.n
+        if n == 0:
+            raise ValueError("an empty graph runs no sweep")
+        i32 = np.int32
+        u = jax.ShapeDtypeStruct((2 * self.size + 1,), self.dtype)
+        if self.ds is not None:
+            args = (jax.ShapeDtypeStruct((n + 1,), i32), u)
+        else:
+            vec, one = (jax.ShapeDtypeStruct((n,), i32),
+                        jax.ShapeDtypeStruct((), i32))
+            args = ((vec, jax.ShapeDtypeStruct((n,), np.bool_), one, vec,
+                     one, one, u),)
+        return self._sweep().lower(*args).compile()
+
     def _build_replay(self):
+        """The jitted replay sweep ``(indeg, u) -> (not_ready, early,
+        undrained, max_width, u)``.  Each level's ops sit in a device
+        scope: ``edt.frontier`` (the level's ids and origin rows),
+        ``edt.validate`` (the schedule checks), ``edt.body`` (the tiles)
+        and ``edt.decrement`` (the out-edges' counter decrement)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
+        tracing.count("sweep.builds")
         dg, ds = self.dg, self.ds
         n, depth, w_pad, e_pad = dg.n, ds.depth, ds.w_pad, ds.e_pad
         validate = self.validate
@@ -449,32 +495,41 @@ class FusedExecutor:
 
             def body(level, carry):
                 indeg, u, not_ready, early, maxw = carry
-                w = tp[level + 1] - tp[level]
-                ids = lax.dynamic_slice(op, (tp[level],), (w_pad,))
+                with jax.named_scope("edt.frontier"):
+                    w = tp[level + 1] - tp[level]
+                    ids = lax.dynamic_slice(op, (tp[level],), (w_pad,))
+                    # mask lanes past this level's width — the fixed-width
+                    # id slice spills into the next level's ids, not the
+                    # sentinel
+                    active = aw < w
+                    orgs = org[ids]
+                    maxw = jnp.maximum(maxw, w)
                 if validate:
                     # same three checks as the decrement-only replay sweep
-                    not_ready += jnp.sum(
-                        jnp.where(aw < w, indeg[ids] != 0, False),
-                        dtype=jnp.int32)
-                    nw = tp[level + 2] - tp[level + 1]
-                    nids = lax.dynamic_slice(op, (tp[level + 1],), (w_pad,))
-                    early += jnp.sum(
-                        jnp.where(aw < nw, indeg[nids] == 0, False),
-                        dtype=jnp.int32)
-                # mask lanes past this level's width — the fixed-width id
-                # slice spills into the next level's ids, not the sentinel
-                u = compute(u, org[ids], active=aw < w)
-                ec = ep[level + 1] - ep[level]
-                tgts = lax.dynamic_slice(tg, (ep[level],), (e_pad,))
-                tgts = jnp.where(ae < ec, tgts, n)
-                indeg = indeg.at[tgts].add(-1)
-                return indeg, u, not_ready, early, jnp.maximum(maxw, w)
+                    with jax.named_scope("edt.validate"):
+                        not_ready += jnp.sum(
+                            jnp.where(active, indeg[ids] != 0, False),
+                            dtype=jnp.int32)
+                        nw = tp[level + 2] - tp[level + 1]
+                        nids = lax.dynamic_slice(op, (tp[level + 1],),
+                                                 (w_pad,))
+                        early += jnp.sum(
+                            jnp.where(aw < nw, indeg[nids] == 0, False),
+                            dtype=jnp.int32)
+                u = compute(u, orgs, active=active)
+                with jax.named_scope("edt.decrement"):
+                    ec = ep[level + 1] - ep[level]
+                    tgts = lax.dynamic_slice(tg, (ep[level],), (e_pad,))
+                    tgts = jnp.where(ae < ec, tgts, n)
+                    indeg = indeg.at[tgts].add(-1)
+                return indeg, u, not_ready, early, maxw
 
             z = jnp.int32(0)
             indeg, u, not_ready, early, maxw = lax.fori_loop(
                 0, depth, body, (indeg, u, z, z, z))
-            undrained = (jnp.sum(indeg[:n] != 0, dtype=jnp.int32)
-                         if validate else z)
+            with jax.named_scope("edt.validate"):
+                undrained = (jnp.sum(indeg[:n] != 0, dtype=jnp.int32)
+                             if validate else z)
             return not_ready, early, undrained, maxw, u
 
         return sweep
@@ -483,13 +538,21 @@ class FusedExecutor:
         import jax.numpy as jnp
 
         dg, ds = self.dg, self.ds
-        if self._replay_fn is None:
-            self._replay_fn = self._build_replay()
-        indeg0 = jnp.concatenate([jnp.asarray(dg.pred_n),
-                                  jnp.zeros(1, jnp.int32)])
-        out = self._replay_fn(indeg0, jnp.asarray(self._flat_state(a0)))
-        not_ready, early, undrained, maxw = (int(x) for x in out[:4])
-        u = np.asarray(out[4])
+        sweep = self._sweep()
+        tracing.count("sweep.tasks", dg.n)
+        tracing.count("sweep.task_lanes", ds.depth * ds.w_pad)
+        tracing.count("sweep.edges", dg.n_edges)
+        tracing.count("sweep.edge_lanes", ds.depth * ds.e_pad)
+        with tracing.span("upload"):
+            indeg0 = jnp.concatenate([jnp.asarray(dg.pred_n),
+                                      jnp.zeros(1, jnp.int32)])
+            u0 = jnp.asarray(self._flat_state(a0))
+        with tracing.span("dispatch"):
+            out = sweep(indeg0, u0)
+        with tracing.span("wait"):
+            not_ready, early, undrained, maxw = (int(x) for x in out[:4])
+        with tracing.span("readback"):
+            u = np.asarray(out[4])
         if not_ready or early or undrained:
             kind, level, ids, indeg = _diagnose_replay(dg, ds)
             counters = _counter_summary(indeg)
@@ -502,10 +565,13 @@ class FusedExecutor:
 
     def _build_discover(self):
         """The jitted discover sweep over the state tuple ``(indeg,
-        frontier, level, level_of, started, max_width, u)``."""
+        frontier, level, level_of, started, max_width, u)``, with the
+        replay sweep's device scopes (no ``edt.validate``: the counters
+        themselves level the graph)."""
         import jax
         import jax.numpy as jnp
 
+        tracing.count("sweep.builds")
         dg = self.dg
         step = (self._pallas_step if self.use_pallas else _step_xla(jnp))
         dec_src = jnp.asarray(dg.dec_src)
@@ -518,12 +584,14 @@ class FusedExecutor:
 
         def body(state):
             indeg, frontier, level, level_of, started, maxw, u = state
-            w = frontier.sum().astype(jnp.int32)
-            level_of = jnp.where(frontier, level, level_of)
+            with jax.named_scope("edt.frontier"):
+                w = frontier.sum().astype(jnp.int32)
+                level_of = jnp.where(frontier, level, level_of)
+                maxw = jnp.maximum(maxw, w)
             u = compute(u, org, active=frontier)
-            indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
-            return (indeg, newly, level + 1, level_of, started + w,
-                    jnp.maximum(maxw, w), u)
+            with jax.named_scope("edt.decrement"):
+                indeg, newly = step(indeg, frontier, dec_src, dec_ptr)
+            return (indeg, newly, level + 1, level_of, started + w, maxw, u)
 
         return jax.jit(lambda s: jax.lax.while_loop(cond, body, s))
 
@@ -532,8 +600,7 @@ class FusedExecutor:
 
         dg = self.dg
         n = dg.n
-        if self._discover_fn is None:
-            self._discover_fn = self._build_discover()
+        sweep = self._sweep()
         pred_host = dg.pred_n
         if self.faults is not None:
             dropped = [int(t) for t in self.faults.dropped_tasks()]
@@ -542,14 +609,18 @@ class FusedExecutor:
                 for t in dropped:
                     pred_host[t] += 1
                     self.faults.record(DROPPED_DECREMENT, t, 0)
-        pred = jnp.asarray(pred_host)
-        init = (pred, pred == 0, jnp.int32(0), jnp.full(n, -1, jnp.int32),
-                jnp.int32(0), jnp.int32(0),
-                jnp.asarray(self._flat_state(a0)))
-        out = self._discover_fn(init)
-        indeg, depth, level_of, started, maxw = (
-            np.asarray(out[i]) for i in (0, 2, 3, 4, 5))
-        u = np.asarray(out[6])
+        with tracing.span("upload"):
+            pred = jnp.asarray(pred_host)
+            init = (pred, pred == 0, jnp.int32(0),
+                    jnp.full(n, -1, jnp.int32), jnp.int32(0), jnp.int32(0),
+                    jnp.asarray(self._flat_state(a0)))
+        with tracing.span("dispatch"):
+            out = sweep(init)
+        with tracing.span("wait"):
+            indeg, depth, level_of, started, maxw = (
+                np.asarray(out[i]) for i in (0, 2, 3, 4, 5))
+        with tracing.span("readback"):
+            u = np.asarray(out[6])
         started = int(started)
         if started != n:
             und = np.flatnonzero(indeg != 0)

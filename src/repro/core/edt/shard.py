@@ -62,6 +62,7 @@ from typing import Optional
 import numpy as np
 
 from ..poly.scanning import LoopNest, shard_polyhedron
+from . import tracing
 from .faults import FaultPlan, maybe_inject
 from .recovery import RetryPolicy, run_round
 
@@ -634,7 +635,9 @@ def scan_sharded(graph, params: dict, shards: int,
                 diag = (_diag_shard_poly(graph, s.key)
                         if td.dep.src == td.dep.tgt else None)
                 jobs.append(_CountJob(s, diag))
-            res, pool = run_round(_job_count, jobs, pool, round_no=0, **rr)
+            with tracing.span("scan.count"):
+                res, pool = run_round(_job_count, jobs, pool, round_no=0,
+                                      **rr)
             for job, n in zip(jobs, res):
                 counts[job.spec] = n
 
@@ -664,7 +667,9 @@ def scan_sharded(graph, params: dict, shards: int,
                     _TileJob(spec=s, slot=_Slot(None, (), 0, -1))
                     for s in specs)
         if tile_jobs:
-            res, pool = run_round(_job_tile, tile_jobs, pool, round_no=1, **rr)
+            with tracing.span("scan.tiles"):
+                res, pool = run_round(_job_tile, tile_jobs, pool,
+                                      round_no=1, **rr)
             _gather(res, tile_parts)
         for key, arr in _merge_pickled(tile_parts).items():
             scans.tiles[key] = arr
@@ -716,8 +721,9 @@ def scan_sharded(graph, params: dict, shards: int,
                     if use:
                         off += counts[s]
             if edge_jobs:
-                res, pool = run_round(_job_edge, edge_jobs, pool,
-                                      round_no=2, **rr)
+                with tracing.span("scan.edges"):
+                    res, pool = run_round(_job_edge, edge_jobs, pool,
+                                          round_no=2, **rr)
                 _gather(res, edge_parts)
             for key, res in _merge_pickled(edge_parts).items():
                 (scans.edges_idx if isinstance(res, tuple)

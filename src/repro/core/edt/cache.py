@@ -22,6 +22,12 @@ Eviction is LRU over whole entries, bounded by
 ``max_bytes`` budget over every stored array.  ``graph_cache_info()``
 exposes hit/miss/eviction counters across all live caches.
 
+An entry also keeps each :class:`~repro.core.edt.fused.FusedExecutor`
+built over its ``fused`` product (:meth:`GraphCache.executor`), one per
+executor key: each holds its jitted sweep, so a warm request runs the
+executable built before.  They go with the entry (eviction, ``clear()``);
+their device memory is not in ``bytes``, which counts host arrays.
+
 Incremental re-materialization
 ------------------------------
 When a request misses but a cached entry exists at params differing only
@@ -120,6 +126,7 @@ class _Entry:
     ds: object = None
     fo: object = None        # fused tile-origin columns (i32[n+1, ndim])
     bytes: int = field(default=0)
+    executors: dict = field(default_factory=dict)   # executor key -> executor
 
 
 class GraphCache:
@@ -143,6 +150,8 @@ class GraphCache:
         self.evictions = 0
         self.incremental_hits = 0
         self.units_reused = 0
+        self.executor_hits = 0
+        self.executor_builds = 0
         _CACHES.add(self)
 
     # ------------------------------------------------------------ plumbing
@@ -332,6 +341,36 @@ class GraphCache:
             fo = self._store(key, params, "fo", fo, int(fo.nbytes))
         return dg, ds, fo
 
+    def executor(self, graph, params: dict, key: tuple, build):
+        """The entry's executor under ``key``; on a miss, ``build()``'s.
+
+        Counts ``executor_hits`` (and the tracing counter
+        ``executor.reused``) or ``executor_builds``, never the products'
+        ``hits``/``misses``.  The built executor is kept only while the
+        entry it was looked up in is still cached, so it never outlives
+        the columns it closes over; a disabled cache keeps none.
+        Concurrent misses may each build; the first stored executor is the
+        one every later call gets.
+        """
+        if not self.policy.enabled:
+            with self._lock:
+                self.executor_builds += 1
+            return build()
+        gkey = self._key(graph, params)
+        with self._lock:
+            ent = self._entries.get(gkey)
+            ex = ent.executors.get(key) if ent is not None else None
+            if ex is not None:
+                self.executor_hits += 1
+                tracing.count("executor.reused")
+                return ex
+        ex = build()
+        with self._lock:
+            self.executor_builds += 1
+            if ent is not None and self._entries.get(gkey) is ent:
+                ex = ent.executors.setdefault(key, ex)
+        return ex
+
     # --------------------------------------------------------- incremental
     def _find_donor_locked(self, key: tuple, graph):
         """Most-recent entry of the same program at different param values."""
@@ -408,6 +447,8 @@ class GraphCache:
                 "evictions": self.evictions,
                 "incremental_hits": self.incremental_hits,
                 "units_reused": self.units_reused,
+                "executor_hits": self.executor_hits,
+                "executor_builds": self.executor_builds,
             }
 
     def clear(self) -> None:
@@ -475,4 +516,6 @@ def graph_cache_info() -> dict:
         "evictions": sum(c["evictions"] for c in caches),
         "incremental_hits": sum(c["incremental_hits"] for c in caches),
         "units_reused": sum(c["units_reused"] for c in caches),
+        "executor_hits": sum(c["executor_hits"] for c in caches),
+        "executor_builds": sum(c["executor_builds"] for c in caches),
     }

@@ -270,11 +270,27 @@ class Session:
         """A :class:`~repro.core.edt.fused.FusedExecutor` over the cached
         fused packed arrays (body/tile inferred from the graph; ``kw``
         forwards ``state=``/``dtype=``/``validate=``/``use_pallas=``...).
+
+        The graph-cache entry keeps the executor it builds under
+        ``replay`` and the executor's arguments
+        (:func:`~repro.core.edt.fused.executor_key`), and hands it back to
+        the next such call: a warm request runs the sweep built before,
+        with no trace, lowering or executable load.  A call that cannot
+        be keyed (``state=``, ``config=``, ...) and a disabled cache
+        build a fresh executor.
         """
-        from .fused import FusedExecutor, graph_tile
+        from .fused import FusedExecutor, executor_key, graph_tile
         ig = self.index_graph(graph, params)
         dg, ds, fo = self.fused_packed(graph, params)
         kw.setdefault("body", getattr(graph.program, "name", "") or None)
         kw.setdefault("tile", graph_tile(graph))
-        return FusedExecutor(ig, params,
-                             packed=(dg, ds if replay else None, fo), **kw)
+
+        def build():
+            return FusedExecutor(ig, params,
+                                 packed=(dg, ds if replay else None, fo),
+                                 **kw)
+
+        key = executor_key(replay, kw)
+        if key is None:
+            return build()
+        return self.cache.executor(graph, params, key, build)

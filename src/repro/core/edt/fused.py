@@ -241,7 +241,11 @@ class FusedExecutor:
     the state's (float64 requires x64 jax — run inside
     ``jax.enable_x64(True)``).  ``run()`` returns a
     :class:`FusedRun`; repeat runs (optionally with a fresh ``state=``)
-    reuse the compiled sweep and pay dispatch cost only.
+    reuse the compiled sweep and pay dispatch cost only.  ``run()`` keeps
+    nothing of a request on the executor (only the sweep, built on first
+    use), so one executor serves any number of requests and threads: a
+    :class:`~repro.core.edt.cache.GraphCache` entry keeps it for
+    :meth:`~repro.core.edt.config.Session.fused_executor`.
     """
 
     def __init__(self, graph: Union[TiledTaskGraph, IndexedGraph],
@@ -411,7 +415,8 @@ class FusedExecutor:
         size = self.size
         grid = self.spec.shape(self.extent)
         state = u[:2 * size].reshape((2,) + grid)
-        final = state[(self.steps - 1) & 1] if self.steps else self._state
+        # v_{-1}, the initial grid, sits in parity buffer 1: T=0 reads it
+        final = state[(self.steps - 1) & 1]
         return FusedRun(mode, levels, level_of, counters, state, final)
 
     # --------------------------------------------------------------- sweeps
@@ -652,3 +657,31 @@ def graph_tile(graph: TiledTaskGraph) -> tuple:
                          f"{sorted(graph.tilings)}")
     (tiling,) = graph.tilings.values()
     return tuple(int(s) for s in tiling.sizes)
+
+
+#: The ``FusedExecutor`` keywords that :func:`executor_key` keys.
+_KEYED = frozenset({"body", "tile", "validate", "dtype", "use_pallas",
+                    "interpret"})
+
+
+def executor_key(replay: bool, kw: dict) -> Optional[tuple]:
+    """The key under which a graph-cache entry keeps the executor that
+    ``FusedExecutor(ig, params, packed=..., **kw)`` builds, or ``None``
+    when the call cannot be keyed: a keyword outside ``body``/``tile``/
+    ``validate``/``dtype``/``use_pallas``/``interpret`` (``state=``,
+    ``config=``, ``session=``...) or a value that cannot be hashed.
+    Defaults are spelled out, so ``dtype=None`` and ``dtype="float32"``
+    key one executor."""
+    if not kw.keys() <= _KEYED:
+        return None
+    dtype, interpret = kw.get("dtype"), kw.get("interpret")
+    try:
+        key = (bool(replay), bool(kw.get("validate", True)),
+               np.dtype(np.float32 if dtype is None else dtype),
+               kw.get("body"), tuple(int(g) for g in kw.get("tile")),
+               bool(kw.get("use_pallas", False)),
+               None if interpret is None else bool(interpret))
+        hash(key)
+    except (TypeError, ValueError):
+        return None
+    return key

@@ -26,8 +26,9 @@ import pytest
 
 from repro.core.edt import (CachePolicy, ExecutionConfig, FusedExecutor,
                             GraphCache, Session, TiledTaskGraph,
-                            graph_tile, host_execute, pack_origins,
-                            simulate_indexed, synthesize_indexed)
+                            graph_cache_info, graph_tile, host_execute,
+                            pack_origins, simulate_indexed,
+                            synthesize_indexed)
 from repro.core.edt.fused import SENTINEL_ORIGIN
 from repro.core.edt.shard import process_pool
 from repro.core.poly import Tiling
@@ -342,6 +343,181 @@ def test_session_fused_executor_end_to_end():
         p2 = s.fused_packed(g, params)
         for a, b in zip(p1, p2):
             assert a is b
+
+
+def _requests(s, g, params, n, **kw):
+    """``n`` requests for one executor; each one's graph-cache and
+    executor counter deltas and whether it built the sweep."""
+    from repro.core.edt import tracing
+
+    out = []
+    for i in range(n):
+        before = s.cache.info()
+        with tracing.recording(), tracing.request(i) as rec:
+            ex = s.fused_executor(g, params, **kw)
+            ex.run()
+        after = s.cache.info()
+        out.append((ex, {k: after[k] - before[k] for k in after
+                         if isinstance(after[k], int)
+                         and not isinstance(after[k], bool)}, rec))
+    return out
+
+
+def test_session_keeps_the_executor_of_a_warm_request():
+    """A warm request gets the executor (and its built sweep) of the
+    request before it; its graph-cache hits and misses are those of the
+    products' own lookups, as without the reuse."""
+    name, tiles, params = CASES[1]
+    g = _graph(name, tiles)
+    with Session() as s:
+        (ex0, d0, cold), (ex1, d1, warm), (ex2, d2, _) = _requests(
+            s, g, params, 3, validate=True)
+        assert ex0 is ex1 is ex2
+        assert "build" in [sp.name for sp in cold.spans]
+        assert cold.counters["sweep.builds"] == 1
+        assert "build" not in [sp.name for sp in warm.spans]
+        assert "sweep.builds" not in warm.counters
+        assert warm.counters["executor.reused"] == 1
+        assert (d0["executor_builds"], d0["executor_hits"]) == (1, 0)
+        assert (d1["executor_builds"], d1["executor_hits"]) == (0, 1)
+        # the products' lookups alone, as a warm request made them before
+        before = s.cache.info()
+        s.index_graph(g, params)
+        s.fused_packed(g, params)
+        after = s.cache.info()
+        assert d1["hits"] == d2["hits"] == after["hits"] - before["hits"] > 0
+        assert d1["misses"] == d2["misses"] == 0
+        info = graph_cache_info()
+        assert info["executor_hits"] >= 2 and info["executor_builds"] >= 1
+
+
+@pytest.mark.parametrize("kw,same", [
+    ({"dtype": "float32"}, True),          # the default, spelled out
+    ({"dtype": None, "validate": True}, True),
+    ({"replay": False}, False),
+    ({"validate": False}, False),
+    ({"dtype": np.float64}, False),
+])
+def test_executor_key_separates_what_builds_another_sweep(kw, same):
+    name, tiles, params = CASES[1]
+    g = _graph(name, tiles)
+    with Session() as s:
+        base = s.fused_executor(g, params)
+        ex = s.fused_executor(g, params, **kw)
+        assert (ex is base) is same
+        assert s.fused_executor(g, params, **kw) is ex
+        assert s.fused_executor(g, params) is base
+        assert s.cache.info()["executor_builds"] == 1 + (not same)
+
+
+@pytest.mark.parametrize("how", ["state", "disabled"])
+def test_unkeyed_calls_build_fresh_executors(how):
+    name, tiles, params = CASES[1]
+    spec = SPECS[name]
+    g = _graph(name, tiles)
+    state = default_state(spec, params["N"], np.float32)
+    policy = CachePolicy(enabled=(how != "disabled"))
+    kw = {"state": state} if how == "state" else {}
+    with Session(ExecutionConfig(cache=policy)) as s:
+        a = s.fused_executor(g, params, **kw)
+        b = s.fused_executor(g, params, **kw)
+        assert a is not b
+        assert np.array_equal(a.run().final, b.run().final)
+        info = s.cache.info()
+        assert info["executor_hits"] == 0
+        # a disabled cache counts its builds; a call with state= never
+        # reaches the cache
+        assert info["executor_builds"] == (2 if how == "disabled" else 0)
+
+
+@pytest.mark.parametrize("how", ["clear", "evict"])
+def test_executor_goes_with_its_cache_entry(how):
+    name, tiles, params = CASES[1]
+    g = _graph(name, tiles)
+    with Session(ExecutionConfig(cache=CachePolicy(max_entries=1))) as s:
+        first = s.fused_executor(g, params)
+        if how == "clear":
+            s.cache.clear()
+        else:
+            s.fused_executor(g, dict(params, N=params["N"] + 1))
+            assert s.cache.info()["evictions"] >= 1
+        again = s.fused_executor(g, params)
+        assert again is not first
+        assert s.fused_executor(g, params) is again
+
+
+def test_reused_executor_carries_nothing_between_requests():
+    """Two seeded grids through one kept executor: bitwise the results
+    of two fresh executors, and the level-major host oracle's."""
+    name, tiles, params = CASES[1]
+    spec = SPECS[name]
+    g = _graph(name, tiles)
+    rng = np.random.default_rng(2**33 + 5)
+    grids = [rng.random(spec.shape(params["N"]), dtype=np.float32)
+             for _ in range(2)]
+    ig, sched = synthesize_indexed(g, params)
+    with Session() as s:
+        kept = [s.fused_executor(g, params).run(a).final for a in grids]
+        kept.append(s.fused_executor(g, params).run(grids[0]).final)
+    for a, got in zip(grids + grids[:1], kept):
+        fresh = FusedExecutor(g, params).run(a).final
+        assert np.array_equal(got, fresh)
+        want = host_execute(spec, tiles, params["T"], params["N"],
+                            pack_origins(ig, tiles), sched.levels, a)
+        np.testing.assert_allclose(got, want, **F32_TOL)
+
+
+def test_zero_step_final_is_the_request_grid():
+    """T=0 answers the grid the request gave, a copy: neither the
+    executor's default grid nor an array a caller could alter it by."""
+    name, tiles, _ = CASES[1]
+    spec = SPECS[name]
+    with Session() as s:
+        ex = s.fused_executor(_graph(name, tiles), {"T": 0, "N": 9})
+        grid = np.ones(spec.shape(9), np.float32)
+        run = ex.run(grid)
+        assert np.array_equal(run.final, grid)
+        run.final[...] = 7
+        assert np.array_equal(ex.run().final,
+                              default_state(spec, 9, np.float32))
+
+
+def test_concurrent_requests_share_the_first_stored_executor():
+    """Threads that miss together may each build; every one of them, and
+    every later call, gets the executor stored first."""
+    import os
+    import sys
+    import threading
+
+    name, tiles, params = CASES[1]
+    g = _graph(name, tiles)
+    n = min(64, 2 * (os.cpu_count() or 4))
+    got = [None] * n
+    with Session() as s:
+        s.fused_packed(g, params)
+        start = threading.Barrier(n)
+
+        def request(i):
+            start.wait(timeout=30)
+            got[i] = s.fused_executor(g, params)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=request, args=(i,))
+                       for i in range(n)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        kept = s.fused_executor(g, params)
+        assert all(ex is kept for ex in got)
+        info = s.cache.info()
+        assert info["executor_builds"] + info["executor_hits"] == n + 1
+        assert info["executor_builds"] >= 1
 
 
 # ========================================================== at scale

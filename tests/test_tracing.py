@@ -99,16 +99,18 @@ def test_fused_replay_records_where_the_work_happens():
     names = [sp.name for sp in cold.spans]
     assert names[:1] == ["scan"] and "level" in names and "pack" in names
     assert names[-5:] == SWEEP_SPANS
-    # the executor keeps its sweep; a new executor builds it again
+    # the executor keeps its sweep, and the session keeps the executor
     assert [sp.name for sp in again.spans] == SWEEP_SPANS[1:]
-    assert [sp.name for sp in warm.spans] == SWEEP_SPANS
+    assert [sp.name for sp in warm.spans] == SWEEP_SPANS[1:]
     ds, dg = ex.ds, ex.dg
     assert cold.counters == {
         "sweep.builds": 1, "sweep.tasks": dg.n,
         "sweep.task_lanes": ds.depth * ds.w_pad,
         "sweep.edges": dg.n_edges, "sweep.edge_lanes": ds.depth * ds.e_pad}
     assert "sweep.builds" not in again.counters
-    assert warm.counters["sweep.builds"] == 1
+    assert "sweep.builds" not in warm.counters
+    assert warm.counters["executor.reused"] == 1
+    assert "executor.reused" not in cold.counters
     c = warm.counters
     assert c["sweep.tasks"] / c["sweep.task_lanes"] == pytest.approx(
         dg.n / (first.counters.depth * first.counters.max_in_flight))
@@ -119,9 +121,9 @@ def test_recording_leaves_the_answer_alone():
     g = _graph()
     with Session() as s:
         off = s.fused_executor(g, PARAMS).run().final
-        with tracing.recording():
-            on = s.fused_executor(g, PARAMS).run().final
-            disc = s.fused_executor(g, PARAMS, replay=False).run().final
+    with Session() as s, tracing.recording():   # built while recording
+        on = s.fused_executor(g, PARAMS).run().final
+        disc = s.fused_executor(g, PARAMS, replay=False).run().final
     assert np.array_equal(off, on)
     assert np.array_equal(off, disc)
 
@@ -182,7 +184,9 @@ def test_record_cell_pairs_requests_on_and_off():
     assert out["requests"]["on"] >= 1
     assert out["requests"]["off"] in (out["requests"]["on"],
                                       out["requests"]["on"] - 1)
-    assert set(SWEEP_SPANS) <= set(out["spans_s"])
+    # the set-up request built the sweep; the window's requests reuse it
+    assert set(SWEEP_SPANS[1:]) <= set(out["spans_s"])
+    assert "build" not in out["spans_s"]
     c = out["counters"]
     assert out["lane_occupancy_pct"] == pytest.approx(
         100 * c["sweep.tasks"] / c["sweep.task_lanes"])

@@ -17,7 +17,9 @@ Prints one JSON line: ``correct``, each request's seconds and whether it
 recorded, the mean seconds of the requests with recording on and off,
 each span's mean host seconds per recorded request,
 each counter per recorded request, the lane occupancy (``sweep.tasks`` /
-``sweep.task_lanes``), for a warm cell the sweep's generated code bytes
+``sweep.task_lanes``), for a wavefront tile body the wave occupancy
+(``sweep.wave_points`` / ``sweep.wave_lanes``: points computed over the
+point lanes its waves sliced), for a warm cell the sweep's generated code bytes
 (``FusedExecutor.compiled()``, read after the window), and with a trace
 the device seconds per solve of each ``edt.*`` scope and the idle gaps
 named by ``bench:`` span, ``edt:`` span and JAX host event
@@ -110,6 +112,9 @@ def record(cell, seed: int, seconds: float, mode: str = "alternate",
         "lane_occupancy_pct": (100.0 * counters["sweep.tasks"]
                                / counters["sweep.task_lanes"]
                                if counters.get("sweep.task_lanes") else None),
+        "wave_occupancy_pct": (100.0 * counters["sweep.wave_points"]
+                               / counters["sweep.wave_lanes"]
+                               if counters.get("sweep.wave_lanes") else None),
         "sweep_code_bytes": code,
     }
     if trace_dir is not None:

@@ -127,19 +127,36 @@ def emit_fused(graph: TiledTaskGraph, body: str = None) -> str:
     steps = " * ".join(str(g) for g in tile)
     out.append(f"  // tile body: {steps} points/tile, taps={len(spec.taps)}"
                f" (dt,off,w), seq dims: t{',' if seq else ''}{','.join(seq)}")
-    out.append(f"  for tt in range({tile[0]}):"
-               "                        // local time: sequential")
-    ind = "    "
-    for d in seq:
-        out.append(f"{ind}for {d} in range(g):                     "
-                   "// Gauss-Seidel dim: sequential")
-        ind += "  "
-    if par:
-        out.append(f"{ind}vmap over ({', '.join(par)}):               "
-                   "// parallel spatial lanes")
-        ind += "  "
-    out.append(f"{ind}t, s = org.t + tt, org.x + l - t        "
-               "// unskew: site = x - t")
+    if spec.wavefront:
+        ind = "      "
+        dims = ["tt"] + [f"l{k}" for k in range(spec.space)]
+        out += [f"  for c in range({sum(tile) - len(tile) + 1}):"
+                "                        // in-tile waves: fori_loop",
+                f"    vmap over wave[c] = {{{', '.join(dims)} : "
+                f"{' + '.join(dims)} = c}}  // padded wave table",
+                f"{ind}t, x = org + (tt, l)                // iteration "
+                "point"]
+        xs = ["t"] + [f"x{k}" for k in range(spec.space)]
+        for k, row in enumerate(spec.site_rows):
+            terms = sorted(((a, xs[j]) for j, a in enumerate(row) if a),
+                           key=lambda term: term[0] < 0)
+            expr = " ".join(f"{'-' if a < 0 else '+'} {x}"
+                            for a, x in terms).lstrip("+ ")
+            out.append(f"{ind}s{k} = {expr:<32} // unskew: site map")
+    else:
+        out.append(f"  for tt in range({tile[0]}):"
+                   "                        // local time: sequential")
+        ind = "    "
+        for d in seq:
+            out.append(f"{ind}for {d} in range(g):                     "
+                       "// Gauss-Seidel dim: sequential")
+            ind += "  "
+        if par:
+            out.append(f"{ind}vmap over ({', '.join(par)}):               "
+                       "// parallel spatial lanes")
+            ind += "  "
+        out.append(f"{ind}t, s = org.t + tt, org.x + l - t        "
+                   "// unskew: site = x - t")
     out.append(f"{ind}mask = 0 <= t < T and s in [0, N)^d     "
                "// = domain membership")
     for dt, off, w in spec.taps:

@@ -32,11 +32,16 @@ task's **tile origin** row (:func:`pack_origins` — tile coords × tile
 sizes, with a sentinel row of negative time at index ``n`` that masks the
 padded lanes), and runs the tile body: local offsets within a tile are a
 *static* structure (``tt`` sequential over the tile's time extent — plus
-sequential spatial dims for Gauss-Seidel — and the parallel spatial dims
-vectorized), so each sub-step is a handful of fused gathers, a weighted
-sum, and one scatter.  Site validity (``0 <= t < T`` and
-``site ∈ [0, N)^d``) is exactly domain membership for the skewed stencil
-programs, so partial tiles mask themselves.
+one sequential spatial dim for 1-D Gauss-Seidel — and the parallel
+spatial dims vectorized), so each sub-step is a handful of fused gathers,
+a weighted sum, and one scatter.  A body with several sequential dims
+(2-D Gauss-Seidel, :attr:`StencilSpec.wavefront`) would unroll a
+sub-step per point; its tile runs instead as a ``lax.fori_loop`` over
+in-tile wavefronts ``tt + l_0 + ... = c`` (:func:`_wave_table`), each
+wave the same gathers, sum and scatter over its points.  Site validity
+(``0 <= t < T`` and ``site ∈ [0, N)^d``, the site given by the spec's
+iteration-to-site map) is exactly domain membership for the skewed
+stencil programs, so partial tiles mask themselves.
 
 Why same-level tiles never race: the EDT flow dependences of these
 stencils cover every write-write and write-read hazard on the parity
@@ -143,11 +148,73 @@ def _local_steps(spec: StencilSpec, tile) -> list:
     return steps
 
 
+def _wave_table(spec: StencilSpec, tile) -> tuple:
+    """The in-tile wavefronts of a :attr:`StencilSpec.wavefront` body.
+
+    Wave ``c`` holds the tile-local points ``(tt, l_0, ...)`` with ``tt +
+    l_0 + ... = c``.  Every tap's iteration distance crosses that
+    hyperplane forward, so a wave reads only points of earlier waves (or
+    of tiles the schedule ran before) and its points are independent.
+    Returns ``(loc, valid)``: the ``(waves, width, 1 + space)`` int32
+    local offsets, each wave in lex order and padded to the widest, and
+    the ``(waves, width)`` mask of its real points.
+    """
+    for dt, off, _ in spec.taps:
+        if sum(spec.distance(dt, off)) <= 0:
+            raise ValueError(
+                f"body {spec.name!r}: tap {(dt, off)} does not cross the "
+                "in-tile wavefront t + x_0 + ... = c forward")
+    pts = np.stack(np.meshgrid(*[np.arange(g) for g in tile],
+                               indexing="ij"), -1).reshape(-1, len(tile))
+    wave = pts.sum(1)
+    counts = np.bincount(wave)
+    order = np.argsort(wave, kind="stable")
+    slot = np.arange(len(order)) - np.repeat(np.cumsum(counts) - counts,
+                                             counts)
+    loc = np.zeros((len(counts), counts.max(), len(tile)), np.int32)
+    valid = np.zeros(loc.shape[:2], bool)
+    loc[wave[order], slot] = pts[order]
+    valid[wave[order], slot] = True
+    return loc, valid
+
+
+def _sites(xp, rows, it):
+    """Site coordinates ``(..., space)`` of iteration points ``it``
+    ``(..., 1 + space)`` under a spec's ``site_rows`` (``xp``: NumPy or
+    ``jax.numpy``)."""
+    out = []
+    for row in rows:
+        acc = None
+        for j, a in enumerate(row):
+            if a:
+                term = it[..., j] if a == 1 else a * it[..., j]
+                acc = term if acc is None else acc + term
+        out.append(acc)
+    return xp.stack(out, -1)
+
+
 def _strides(space: int, extent: int) -> tuple:
     return tuple(extent ** (space - 1 - k) for k in range(space))
 
 
 # --------------------------------------------------------------- host oracle
+def _tile_points(spec: StencilSpec, tile, org: "np.ndarray"):
+    """The tile body's points in execution order, for the lanes whose
+    origin rows are ``org`` ``(w, 1 + space)``: per sub-step (or per
+    wave of a :attr:`StencilSpec.wavefront` body) the times ``t``
+    ``(w, 1)`` or ``(w, p)`` and sites ``(w, p, space)``."""
+    if spec.wavefront:
+        rows = spec.site_rows
+        for loc, ok in zip(*_wave_table(spec, tile)):
+            it = org[:, None, :] + loc[ok][None].astype(np.int64)
+            yield it[..., 0], _sites(np, rows, it)
+        return
+    t0, osp = org[:, 0], org[:, 1:]
+    for tt, loc in _local_steps(spec, tile):
+        t = (t0 + tt)[:, None]
+        yield t, osp[:, None, :] + loc[None].astype(np.int64) - t[..., None]
+
+
 def host_execute(spec: StencilSpec, tile, steps: int, extent: int,
                  origins: "np.ndarray", levels, state: "np.ndarray"):
     """Level-major NumPy twin of the fused sweep (the host-dispatch path).
@@ -163,19 +230,14 @@ def host_execute(spec: StencilSpec, tile, steps: int, extent: int,
     st = np.asarray(_strides(space, extent), dtype=np.int64)
     u = np.zeros((2, size), dtype=state.dtype)
     u[1] = state.ravel()
-    loc_steps = _local_steps(spec, tile)
     ty = state.dtype.type
     for ids in levels:
         org = origins[np.asarray(ids)].astype(np.int64)
-        t0, osp = org[:, 0], org[:, 1:]
-        for tt, loc in loc_steps:
-            t = t0 + tt
-            site = osp[:, None, :] + loc[None].astype(np.int64) \
-                - t[:, None, None]
-            ok0 = ((t >= 0) & (t < steps))[:, None] \
+        for t, site in _tile_points(spec, tile, org):
+            ok0 = (t >= 0) & (t < steps) \
                 & np.all((site >= 0) & (site < extent), axis=2)
             flat = site @ st
-            pw = (t & 1)[:, None]
+            pw = t & 1
             acc = np.zeros(flat.shape, dtype=u.dtype)
             for dt, off, w in spec.taps:
                 ok = ok0
@@ -337,7 +399,10 @@ class FusedExecutor:
                 f"{spec.shape(self.extent)}")
         self.use_pallas = use_pallas
         self.interpret = interpret
-        self._loc_steps = _local_steps(spec, tile)
+        if spec.wavefront:
+            self._loc_steps, self._waves = None, _wave_table(spec, tile)
+        else:
+            self._loc_steps, self._waves = _local_steps(spec, tile), None
         self._replay_fn = None
         self._discover_fn = None
         if use_pallas:
@@ -366,7 +431,12 @@ class FusedExecutor:
         themselves through ``t < 0``); ``active`` optionally masks lanes
         (the discover frontier).  Sub-steps unroll statically; each is
         3^d masked gathers, a weighted sum, and one dropped-OOB scatter,
-        all under the device scope ``edt.body``.
+        all under the device scope ``edt.body``.  A wavefront body runs
+        its tile as a ``lax.fori_loop`` over the waves of
+        :func:`_wave_table` instead, each wave every lane's points at
+        once; picking a wave's points (its row of the wave table, the
+        sites, the mask, the flat indices) sits in the device scope
+        ``edt.wave``, its gathers, sum and scatter in ``edt.body``.
         """
         import jax
 
@@ -375,6 +445,56 @@ class FusedExecutor:
         st = _strides(spec.space, extent)
         loc_steps = self._loc_steps
         taps = spec.taps
+
+        def apply_taps(u, site, flat, ok0, pw):
+            """The points' gathers, weighted sum and scatter; ``pw`` is the
+            parity offset per lane, or per point of a wave."""
+            acc = jnp.zeros(flat.shape, u.dtype)
+            for dt, off, w in taps:
+                ok = ok0
+                foff = 0
+                for k, o in enumerate(off):
+                    if o:
+                        ns = site[..., k] + o
+                        ok = ok & (ns >= 0) & (ns < extent)
+                        foff += o * st[k]
+                base = pw if dt == 0 else size - pw
+                if base.ndim < flat.ndim:
+                    base = base[:, None]
+                idx = jnp.where(ok, base + flat + foff, 2 * size)
+                acc = acc + w * u[idx]
+            if pw.ndim < flat.ndim:
+                pw = pw[:, None]
+            widx = jnp.where(ok0, pw + flat, 2 * size + 1)
+            return u.at[widx.reshape(-1)].set(acc.reshape(-1), mode="drop")
+
+        if spec.wavefront:
+            from jax import lax
+
+            rows = spec.site_rows
+            locs, valid = (jnp.asarray(a) for a in self._waves)
+
+            @jax.named_scope("edt.body")
+            def waves(u, org, active=None):
+                def wave(c, u):
+                    with jax.named_scope("edt.wave"):
+                        it = org[:, None, :] + locs[c][None]
+                        t = it[..., 0]
+                        site = _sites(jnp, rows, it)
+                        ok0 = (valid[c][None] & (t >= 0) & (t < steps)
+                               & jnp.all((site >= 0) & (site < extent),
+                                         axis=2))
+                        if active is not None:
+                            ok0 = ok0 & active[:, None]
+                        flat = site[..., 0] * st[0]
+                        for k in range(1, spec.space):
+                            flat = flat + site[..., k] * st[k]
+                        pw = (t & 1) * size
+                    return apply_taps(u, site, flat, ok0, pw)
+
+                return lax.fori_loop(0, locs.shape[0], wave, u)
+
+            return waves
 
         @jax.named_scope("edt.body")
         def compute(u, org, active=None):
@@ -393,20 +513,7 @@ class FusedExecutor:
                 flat = site[..., 0] * st[0]
                 for k in range(1, spec.space):
                     flat = flat + site[..., k] * st[k]
-                acc = jnp.zeros(flat.shape, u.dtype)
-                for dt, off, w in taps:
-                    ok = ok0
-                    foff = 0
-                    for k, o in enumerate(off):
-                        if o:
-                            ns = site[..., k] + o
-                            ok = ok & (ns >= 0) & (ns < extent)
-                            foff += o * st[k]
-                    base = pw if dt == 0 else size - pw
-                    idx = jnp.where(ok, base[:, None] + flat + foff, 2 * size)
-                    acc = acc + w * u[idx]
-                widx = jnp.where(ok0, pw[:, None] + flat, 2 * size + 1)
-                u = u.at[widx.reshape(-1)].set(acc.reshape(-1), mode="drop")
+                u = apply_taps(u, site, flat, ok0, pw)
             return u
 
         return compute
@@ -456,6 +563,11 @@ class FusedExecutor:
         ``memory_analysis()`` gives the generated code's bytes, and its
         ``as_text()`` the HLO, each op's metadata naming its ``edt.*``
         device scope."""
+        return self.lowered().compile()
+
+    def lowered(self):
+        """The sweep :meth:`run` calls, lowered for this executor's own
+        argument shapes; its ``as_text()`` is the program before XLA."""
         import jax
 
         n = self.dg.n
@@ -470,7 +582,7 @@ class FusedExecutor:
                         jax.ShapeDtypeStruct((), i32))
             args = ((vec, jax.ShapeDtypeStruct((n,), np.bool_), one, vec,
                      one, one, u),)
-        return self._sweep().lower(*args).compile()
+        return self._sweep().lower(*args)
 
     def _build_replay(self):
         """The jitted replay sweep ``(indeg, u) -> (not_ready, early,
@@ -548,6 +660,12 @@ class FusedExecutor:
         tracing.count("sweep.task_lanes", ds.depth * ds.w_pad)
         tracing.count("sweep.edges", dg.n_edges)
         tracing.count("sweep.edge_lanes", ds.depth * ds.e_pad)
+        if self._waves is not None:
+            waves, width = self._waves[1].shape
+            tracing.count("sweep.body_waves", ds.depth * waves)
+            tracing.count("sweep.wave_points", self.steps * self.size)
+            tracing.count("sweep.wave_lanes",
+                          ds.depth * ds.w_pad * width * waves)
         with tracing.span("upload"):
             indeg0 = jnp.concatenate([jnp.asarray(dg.pred_n),
                                       jnp.zeros(1, jnp.int32)])

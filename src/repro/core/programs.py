@@ -84,6 +84,50 @@ def seidel1d() -> PolyhedralProgram:
     return P
 
 
+def seidel2d() -> PolyhedralProgram:
+    """PolyBench seidel-2d: the in-place 9-point Gauss-Seidel sweep.
+
+    ``A[i][j]`` becomes the mean of its 3x3 box; the reads at ``(i-1,
+    j-1)``, ``(i-1, j)``, ``(i-1, j+1)`` and ``(i, j-1)`` see this sweep's
+    values, the other five the previous sweep's.  Under the skew ``(t, x0,
+    x1) = (t, t + i, 2t + i + j)`` the nine distances (target minus source)
+    are non-negative, so any orthogonal tiling is legal:
+
+      this sweep (dt = 0):     (0,1,0), (0,1,1), (0,1,2), (0,0,1)
+      previous sweep (dt = 1): (1,0,0), (1,0,1), (1,0,2), (1,1,1), (1,1,2)
+
+    written as four convex dependences: ``row`` (0,1,0..2), ``col``
+    (0,0,1), ``carry`` (1,0,0..2) and ``carry_diag`` (1,1,1..2).  Domain
+    {(t,x0,x1) : 0<=t<T, t<=x0<t+N, t+x0<=x1<t+x0+N}, a parallelepiped
+    with partial tiles along its two skewed faces.  Params (T, N).
+    """
+    P = PolyhedralProgram()
+    D = Polyhedron.from_ineqs(
+        ("t", "x0", "x1"), ("T", "N"),
+        [(1, 0, 0, 0, 0, 0), (-1, 0, 0, 1, 0, -1),     # 0 <= t <= T-1
+         (-1, 1, 0, 0, 0, 0), (1, -1, 0, 0, 1, -1),    # t <= x0 <= t+N-1
+         (-1, -1, 1, 0, 0, 0),                         # x1 >= t + x0
+         (1, 1, -1, 0, 1, -1)])                        # x1 <= t+x0+N-1
+    P.add_statement("S", D)
+    # rows: (t_s, x0_s, x1_s, t_t, x0_t, x1_t, T, N, const)
+    same_t = (1, 0, 0, -1, 0, 0, 0, 0, 0)              # t_t = t_s
+    next_t = (1, 0, 0, -1, 0, 0, 0, 0, 1)              # t_t = t_s + 1
+    same_x0 = (0, 1, 0, 0, -1, 0, 0, 0, 0)             # x0_t = x0_s
+    next_x0 = (0, 1, 0, 0, -1, 0, 0, 0, 1)             # x0_t = x0_s + 1
+
+    for name, t_eq, x0_eq, lo, hi in (("row", same_t, next_x0, 0, 2),
+                                      ("col", same_t, same_x0, 1, 1),
+                                      ("carry", next_t, same_x0, 0, 2),
+                                      ("carry_diag", next_t, next_x0, 1, 2)):
+        if lo == hi:                                   # x1_t = x1_s + lo
+            eqs, ineqs = [t_eq, x0_eq, (0, 0, 1, 0, 0, -1, 0, 0, lo)], []
+        else:                                          # lo <= x1_t-x1_s <= hi
+            eqs, ineqs = [t_eq, x0_eq], [(0, 0, -1, 0, 0, 1, 0, 0, -lo),
+                                         (0, 0, 1, 0, 0, -1, 0, 0, hi)]
+        P.add_dependence("S", "S", dep(D, D, eqs=eqs, ineqs=ineqs), name)
+    return P
+
+
 def jacobi2d() -> PolyhedralProgram:
     """Jacobi-2D (9-point), skewed both space dims: offsets in {0,1,2}^2."""
     P = PolyhedralProgram()
@@ -387,6 +431,7 @@ def _named(name: str, build):
 PROGRAMS = {name: _named(name, fn) for name, fn in {
     "stencil1d": stencil1d,
     "seidel1d": seidel1d,
+    "seidel2d": seidel2d,
     "jacobi2d": jacobi2d,
     "heat3d": heat3d,
     "matmul": matmul,

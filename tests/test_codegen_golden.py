@@ -93,3 +93,15 @@ def test_fused_emitter_reflects_the_spec():
     assert text.count("acc +=") == len(SPECS["seidel1d"].taps)
     assert "u[p, s + (-1,)]" in text      # dt=0 tap reads the same parity
     assert "u[1-p, s + (1,)]" in text     # dt=1 tap reads the other parity
+
+
+def test_fused_emitter_renders_the_wave_loop():
+    """A wavefront body renders its in-tile wave loop and its own site
+    map, and no per-dimension Gauss-Seidel loops."""
+    s = TiledTaskGraph(PROGRAMS["seidel2d"](), {"S": Tiling((2, 3, 4))})
+    text = emit_fused(s)
+    assert "for c in range(7):" in text          # 2 + 3 + 4 - 2 waves
+    assert "tt + l0 + l1 = c" in text
+    assert "s0 = x0 - t " in text and "s1 = x1 - t - x0 " in text
+    assert "Gauss-Seidel dim" not in text
+    assert text.count("acc +=") == len(SPECS["seidel2d"].taps)

@@ -20,6 +20,8 @@ graph-cache ``fused`` product, and the ≥1M-task jacobi2d acceptance run.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -44,6 +46,10 @@ CASES = [
     ("jacobi2d", (2, 2, 2), {"T": 5, "N": 11}),
     ("heat3d", (2, 2, 2, 2), {"T": 3, "N": 7}),
     ("seidel1d", (2, 3), {"T": 6, "N": 14}),
+    # 2-D Gauss-Seidel: in-tile wavefronts under its own skew; the second
+    # tile cuts both skewed faces of the parallelepiped at other sizes
+    ("seidel2d", (2, 3, 4), {"T": 5, "N": 11}),
+    ("seidel2d", (3, 4, 5), {"T": 7, "N": 9}),
 ]
 
 F32_TOL = dict(rtol=1e-5, atol=1e-6)    # observed ~1 ULP (6e-8)
@@ -518,6 +524,133 @@ def test_concurrent_requests_share_the_first_stored_executor():
         info = s.cache.info()
         assert info["executor_builds"] + info["executor_hits"] == n + 1
         assert info["executor_builds"] >= 1
+
+
+# ================================================ 2-D Gauss-Seidel
+def _seidel_iteration(t, s0, s1):
+    """PolyBench seidel-2d's skew: site (i, j) at sweep t is the point
+    (t, t + i, 2t + i + j)."""
+    return np.stack([t, t + s0, 2 * t + s0 + s1], -1)
+
+
+@pytest.mark.parametrize("tiles,params", [
+    ((2, 3, 4), {"T": 5, "N": 11}), ((3, 4, 5), {"T": 7, "N": 9}),
+    ((2, 8, 8), {"T": 4, "N": 13})])
+def test_seidel2d_tile_graph_orders_every_point_read(tiles, params):
+    """Every one of the nine reads of every point, taken from the taps
+    and PolyBench's skew (not from the program's dependences), has its
+    source in a tile at a lower level, or in the same tile at an earlier
+    in-tile wave."""
+    spec = SPECS["seidel2d"]
+    ig, sched = synthesize_indexed(_graph("seidel2d", tiles), params)
+    coords = ig.stmt_blocks[0][1].astype(np.int64)
+    level = {tuple(c): int(lv) for c, lv in zip(coords, sched.level_of)}
+    size = np.asarray(tiles)
+    steps, n = params["T"], params["N"]
+    t, s0, s1 = (a.ravel() for a in np.meshgrid(
+        np.arange(steps), np.arange(n), np.arange(n), indexing="ij"))
+    tgt = _seidel_iteration(t, s0, s1)
+    for dt, off, _ in spec.taps:
+        ts, a, b = t - dt, s0 + off[0], s1 + off[1]
+        ok = (ts >= 0) & (a >= 0) & (a < n) & (b >= 0) & (b < n)
+        src = _seidel_iteration(ts[ok], a[ok], b[ok])
+        dst = tgt[ok]
+        st, dtile = src // size, dst // size
+        lv_src = np.asarray([level[tuple(c)] for c in st])
+        lv_dst = np.asarray([level[tuple(c)] for c in dtile])
+        same = np.all(st == dtile, axis=1)
+        wave_src = (src - st * size).sum(1)
+        wave_dst = (dst - dtile * size).sum(1)
+        assert np.all(np.where(same, wave_src < wave_dst, lv_src < lv_dst)), \
+            (dt, off)
+        assert ok.any(), (dt, off)
+
+
+def test_seidel2d_distances_are_the_programs():
+    """The spec's taps, through its site map, are the nine dependence
+    distances of ``programs.seidel2d``."""
+    want = {(0, 1, 0), (0, 1, 1), (0, 1, 2), (0, 0, 1), (1, 0, 0),
+            (1, 0, 1), (1, 0, 2), (1, 1, 1), (1, 1, 2)}
+    spec = SPECS["seidel2d"]
+    assert {spec.distance(dt, off) for dt, off, _ in spec.taps} == want
+    assert spec.wavefront and not SPECS["seidel1d"].wavefront
+    assert not SPECS["jacobi2d"].wavefront
+    # the default map is x - t: a jacobi tap one step back at offset -1
+    assert SPECS["jacobi2d"].distance(1, (-1, 1)) == (1, 2, 0)
+
+
+@pytest.mark.parametrize("tile", [(2, 3, 4), (2, 64, 64), (1, 1, 1)])
+def test_wave_table_covers_each_tile_point_once(tile):
+    from repro.core.edt.fused import _wave_table
+
+    loc, valid = _wave_table(SPECS["seidel2d"], tile)
+    assert loc.shape[0] == sum(tile) - len(tile) + 1
+    pts = loc[valid]
+    assert len(pts) == np.prod(tile)
+    assert len(np.unique(pts, axis=0)) == len(pts)
+    waves = np.repeat(np.arange(loc.shape[0]), valid.sum(1))
+    assert np.array_equal(pts.sum(1), waves)
+    if tile == (2, 64, 64):
+        assert loc.shape[1] == 127       # 63 + 64 points at c = 64
+
+
+def test_wave_table_refuses_a_tap_that_does_not_cross_forward():
+    from repro.core.edt.fused import _wave_table
+    from repro.kernels.stencils import StencilSpec
+
+    bad = StencilSpec("bad", 2, ((0, (1, 0), 1.0),), (True, True),
+                      site_map=SPECS["seidel2d"].site_map)
+    with pytest.raises(ValueError, match="wavefront"):
+        _wave_table(bad, (2, 2, 2))
+
+
+def test_seidel2d_session_replay_matches_the_benchmark_reference():
+    """The normal path (``Session.graph`` -> ``fused_executor(replay=True)``
+    -> ``run(state)``) against the chip benchmark's float64 reference,
+    which imports nothing of the program, on a seeded random grid."""
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.chip.seidel_reference import seidel_solve
+
+    params = {"T": 5, "N": 23}
+    state = np.random.default_rng(2**33 + 5).random((23, 23),
+                                                    dtype=np.float32)
+    with Session() as s:
+        g = s.graph(PROGRAMS["seidel2d"](), {"S": Tiling((2, 8, 8))})
+        ex = s.fused_executor(g, params, replay=True)
+        got = ex.run(state).final
+    np.testing.assert_allclose(got, seidel_solve(state, params["T"]),
+                               **F32_TOL)
+    np.testing.assert_allclose(
+        got, reference_solve(SPECS["seidel2d"], state, params["T"]),
+        **F32_TOL)
+
+
+def _golden_lowerings() -> list:
+    path = Path(__file__).parent / "golden" / "fused_jacobi2d_lowered.sha256"
+    rows = [ln.split() for ln in path.read_text().splitlines()
+            if ln and not ln.startswith("#")]
+    return [(tuple(map(int, tile.split("x"))),
+             {k: int(v) for k, v in (t.split("=") for t in (tp, np_))},
+             mode, digest) for tile, tp, np_, mode, digest in rows]
+
+
+@pytest.mark.parametrize("tile,params,mode,digest", _golden_lowerings())
+def test_jacobi2d_sweep_lowers_as_before(tile, params, mode, digest):
+    """The site map and the wavefront body leave a Jacobi sweep's program
+    as it was: the lowered text of the fine (2,2,2) and coarse (8,64,64)
+    jacobi2d sweeps hashes to the digests taken before they came."""
+    import hashlib
+
+    ig, sched = synthesize_indexed(_graph("jacobi2d", tile), params)
+    ex = FusedExecutor(ig, params, body="jacobi2d", tile=tile,
+                       schedule=sched if mode == "replay" else None)
+    text = ex.lowered().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 # ========================================================== at scale

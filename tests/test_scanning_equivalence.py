@@ -22,6 +22,7 @@ from repro.core.programs import PROGRAMS
 CASES = {
     "stencil1d": ((2, 3), {"T": 5, "N": 9}),
     "seidel1d": ((2, 2), {"T": 4, "N": 7}),
+    "seidel2d": ((2, 2, 3), {"T": 3, "N": 4}),
     "jacobi2d": ((2, 2, 2), {"T": 3, "N": 5}),
     "heat3d": ((2, 2, 2, 2), {"T": 3, "N": 4}),
     "matmul": ((2, 2, 2), {"N": 5}),

@@ -26,6 +26,9 @@ TILE = (2, 2, 2)
 #: sweeps at 33,800 tasks (depth 150), the discover sweeps, whose compile
 #: time grows faster with the graph, at 4,356 tasks (depth 74).
 PARAMS = {"replay": {"T": 16, "N": 128}, "discover": {"T": 8, "N": 64}}
+#: The 2-D Gauss-Seidel replay sweep at its benchmark tile: 128 in-tile
+#: waves of up to 127 points, in a loop whatever the grid.
+SEIDEL_TILE, SEIDEL_PARAMS = (2, 64, 64), {"T": 4, "N": 256}
 
 
 @pytest.fixture(scope="module")
@@ -67,6 +70,13 @@ def jacobi():
             for mode, params in PARAMS.items()}
 
 
+@pytest.fixture(scope="module")
+def seidel():
+    g = TiledTaskGraph(PROGRAMS["seidel2d"](), {"S": Tiling(SEIDEL_TILE)},
+                       backend="numpy")
+    return synthesize_indexed(g, SEIDEL_PARAMS)
+
+
 def _spec(one_chip, shape, dtype):
     import jax
     return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=one_chip)
@@ -83,6 +93,12 @@ def _discover_state(one_chip, n, extra=()):
 def _lowered(kind, ig, sched, one_chip):
     n = ig.n
     replay = kind.endswith("replay")
+    if kind == "seidel2d-replay":
+        ex = FusedExecutor(ig, SEIDEL_PARAMS, body="seidel2d",
+                           tile=SEIDEL_TILE, schedule=sched)
+        return ex._build_replay().lower(
+            _spec(one_chip, (n + 1,), "int32"),
+            _spec(one_chip, (2 * ex.size + 1,), "float32"))
     if kind.startswith("fused"):
         params = PARAMS["replay" if replay else "discover"]
         ex = FusedExecutor(ig, params, body="jacobi2d", tile=TILE,
@@ -101,9 +117,14 @@ def _lowered(kind, ig, sched, one_chip):
 
 
 @pytest.mark.parametrize("kind", ["fused-replay", "fused-discover",
-                                  "device-replay", "device-discover"])
-def test_sweep_compiles_for_v5e(kind, jacobi, one_chip, no_cache):
-    ig, sched = jacobi["replay" if kind.endswith("replay") else "discover"]
+                                  "device-replay", "device-discover",
+                                  "seidel2d-replay"])
+def test_sweep_compiles_for_v5e(kind, request, one_chip, no_cache):
+    if kind.startswith("seidel2d"):
+        ig, sched = request.getfixturevalue("seidel")
+    else:
+        ig, sched = request.getfixturevalue("jacobi")[
+            "replay" if kind.endswith("replay") else "discover"]
     compiled = _lowered(kind, ig, sched, one_chip).compile()
     mem = compiled.memory_analysis()
     # one chip holds 16 GB; these sweeps need a small fraction of it

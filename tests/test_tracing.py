@@ -128,14 +128,18 @@ def test_recording_leaves_the_answer_alone():
     assert np.array_equal(off, disc)
 
 
-def _scoped_ops(hlo: str) -> dict:
-    """``edt.*`` scope -> opcodes of the HLO instructions under it."""
+def _scoped_ops(hlo: str, innermost: bool = False) -> dict:
+    """``edt.*`` scope -> opcodes of the HLO instructions under it: the
+    outermost ``edt.*`` scope of each op, or with ``innermost`` the
+    innermost, as the benchmark's trace reduction attributes it."""
     out = {}
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(", line)
-        scope = re.search(r'op_name="[^"]*?(edt\.\w+)', line)
-        if m and scope:
-            out.setdefault(scope.group(1), set()).add(m.group(1))
+        name = re.search(r'op_name="([^"]*)"', line)
+        scopes = re.findall(r"edt\.\w+", name.group(1)) if name else []
+        if m and scopes:
+            scope = scopes[-1] if innermost else scopes[0]
+            out.setdefault(scope, set()).add(m.group(1))
     return out
 
 
@@ -159,6 +163,56 @@ def test_compiled_sweep_names_its_scopes(replay):
             assert "edt.validate" not in ops
         ex.run()                  # the compiled sweep is the one run calls
         assert ex._sweep() is ex._sweep()
+
+
+def _seidel_graph(tile=(2, 3, 4)):
+    return TiledTaskGraph(PROGRAMS["seidel2d"](), {"S": Tiling(tile)},
+                          backend="numpy")
+
+
+def test_wavefront_body_counts_its_waves():
+    """A 2-D Gauss-Seidel solve counts its in-tile waves, the domain
+    points they computed and the point lanes they sliced; the numbers
+    follow from the tile and the graph's depth and widest level."""
+    import itertools
+
+    tile = (2, 3, 4)
+    pts = list(itertools.product(*map(range, tile)))
+    width = max(sum(1 for p in pts if sum(p) == c)
+                for c in range(sum(tile)))
+    waves = sum(tile) - len(tile) + 1
+    with Session() as s, tracing.recording():
+        with tracing.request(0) as rec:
+            ex = s.fused_executor(_seidel_graph(tile), PARAMS)
+            run = ex.run()
+    depth, w_pad = run.counters.depth, run.counters.max_in_flight
+    assert (waves, width) == (7, 6)
+    c = rec.counters
+    assert c["sweep.body_waves"] == depth * waves
+    assert c["sweep.wave_points"] == PARAMS["T"] * PARAMS["N"] ** 2
+    assert c["sweep.wave_lanes"] == depth * w_pad * width * waves
+    assert 0 < c["sweep.wave_points"] < c["sweep.wave_lanes"]
+    with Session() as s, tracing.recording():
+        with tracing.request(0) as jac:
+            s.fused_executor(_graph(), PARAMS).run()
+    assert not any(k.startswith("sweep.wave") or k == "sweep.body_waves"
+                   for k in jac.counters)
+
+
+def test_compiled_wavefront_body_names_its_wave_scope():
+    """The ops that pick a wave's points sit in ``edt.wave``; the wave's
+    gathers and scatter stay in ``edt.body``; a Jacobi body has no
+    ``edt.wave``."""
+    with Session() as s:
+        ops = _scoped_ops(s.fused_executor(_seidel_graph(), PARAMS)
+                          .compiled().as_text(), innermost=True)
+        jacobi = _scoped_ops(s.fused_executor(_graph(), PARAMS)
+                             .compiled().as_text(), innermost=True)
+    # the wave table's row, and the sites' and flat indices' arithmetic
+    assert {"dynamic-slice", "add", "compare"} <= ops["edt.wave"]
+    assert {"gather", "scatter"} <= ops["edt.body"]
+    assert not {"gather", "scatter"} & ops["edt.wave"]
+    assert "edt.wave" not in jacobi
 
 
 def test_compiled_refuses_an_empty_graph():
@@ -191,3 +245,22 @@ def test_record_cell_pairs_requests_on_and_off():
     assert out["lane_occupancy_pct"] == pytest.approx(
         100 * c["sweep.tasks"] / c["sweep.task_lanes"])
     assert out["sweep_code_bytes"] is not None
+
+
+def test_record_cell_reports_wave_occupancy():
+    for p in (str(ROOT), str(ROOT / "src")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchmarks.chip import harness
+    from benchmarks.record_cell import record
+
+    cell = harness.load_cell("seidel2d-large.warm")
+    cell.config.update(N=20, TSTEPS=3, shards=0, tile=[2, 8, 8])
+    cell.mix["warmup_seconds"] = 1e-3
+    out = record(cell, 2**33 + 2, 0.3, log=lambda m: None)
+    assert out["correct"], out["checks"]
+    c = out["counters"]
+    assert out["requests"]["on"] >= 1
+    assert c["sweep.wave_points"] == 3 * 20 ** 2     # T · N², per request
+    assert out["wave_occupancy_pct"] == pytest.approx(
+        100 * c["sweep.wave_points"] / c["sweep.wave_lanes"])
